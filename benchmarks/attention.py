@@ -25,9 +25,9 @@ block-pattern masks of ~6/12/25/50% live tiles, recording tokens/s, the
 speedup, and GFLOP/s-skipped (the avoided-FLOP rate: how much dense work
 per wall-second the skipped tiles would have cost).  A causal-parity pair
 rides along: the row-extent banded grid vs the legacy ``pl.when``
-full-grid causal kernel.  Both run the interpret plane off-TPU, where
-per-tile work is the whole cost — the tokens/s ratio *is* the
-launched-tile ratio, which is the claim that carries to TPU.
+full-grid causal kernel.  Both run on the registry's plane: compiled on a
+TPU, interpreted when ``REPRO_KERNELS=interpret`` asks for it, and left out
+(with a printed reason) on the XLA plane, which has no such kernel.
 
     PYTHONPATH=src python -m benchmarks.run --only attention
     PYTHONPATH=src python -m benchmarks.run --only attention --json-out a.json
@@ -75,14 +75,16 @@ def _block_pattern(nq: int, nk: int, density: float, seed: int = 0):
     return pat
 
 
-def density_sweep() -> list[dict]:
-    """Blocksparse vs dense-masked A/B per mask density + causal parity."""
+def density_sweep(plane: str) -> list[dict]:
+    """Blocksparse vs dense-masked A/B per mask density + causal parity,
+    on the ``pallas`` or ``interpret`` plane."""
     import jax
 
     from repro.kernels import flash_attention as fa_k
     from repro.sparse.maskcompiler import (MaskSpec, compile_layout,
                                            dense_masked_layout)
 
+    interpret = plane == "interpret"
     L, blk = SWEEP_L, SWEEP_BLOCK
     nq = nk = L // blk
     q, k, v = _qkv(L)
@@ -94,9 +96,9 @@ def density_sweep() -> list[dict]:
         lay = compile_layout(spec, L, L, blk, blk)
         base = dense_masked_layout(spec, L, L, blk, blk)
         run_bs = jax.jit(lambda q, k, v, lay=lay: fa_k.flash_attention_tiles(
-            q, k, v, lay, interpret=True))
+            q, k, v, lay, interpret=interpret))
         run_dm = jax.jit(lambda q, k, v, lay=base: fa_k.flash_attention_tiles(
-            q, k, v, lay, interpret=True))
+            q, k, v, lay, interpret=interpret))
         t_bs = time_fn(run_bs, q, k, v, warmup=1, iters=3)
         t_dm = time_fn(run_dm, q, k, v, warmup=1, iters=3)
         rows.append({
@@ -112,10 +114,10 @@ def density_sweep() -> list[dict]:
 
     # causal parity: banded row extents vs the legacy pl.when full grid
     run_ext = jax.jit(lambda q, k, v: fa_k.flash_attention(
-        q, k, v, causal=True, block_q=blk, block_k=blk, interpret=True))
+        q, k, v, causal=True, block_q=blk, block_k=blk, interpret=interpret))
     run_when = jax.jit(lambda q, k, v: fa_k.flash_attention(
         q, k, v, causal=True, block_q=blk, block_k=blk, row_extents=False,
-        interpret=True))
+        interpret=interpret))
     t_ext = time_fn(run_ext, q, k, v, warmup=1, iters=3)
     t_when = time_fn(run_when, q, k, v, warmup=1, iters=3)
     causal_density = (nq + 1) / (2 * nk)
@@ -135,7 +137,7 @@ def density_sweep() -> list[dict]:
 def main(full: bool = False) -> list[dict]:
     import jax
 
-    from repro.core import ExecLevel, compat, registry, use_level
+    from repro.core import ExecLevel, registry, use_level
     from repro.distributed.collectives import ring_plan
     from repro.kernels import ops
 
@@ -145,8 +147,9 @@ def main(full: bool = False) -> list[dict]:
     ring = 1 << (min(jax.device_count(), 8).bit_length() - 1)
     mesh = None
     if ring > 1:
-        mesh = compat.make_mesh((ring, 1), ("data", "model"),
-                                devices=jax.devices()[:ring])
+        mesh = jax.make_mesh((ring, 1), ("data", "model"),
+                             (jax.sharding.AxisType.Auto,) * 2,
+                             devices=jax.devices()[:ring])
         ring = ring_plan(mesh).size
     else:
         print("attention suite: 1 device visible — ring rows degrade to "
@@ -175,9 +178,15 @@ def main(full: bool = False) -> list[dict]:
                 f"GQA {H}:{HK} heads, d={D})", rows,
                 ["L", "mode", "variant", "ring", "seconds", "tokens_per_s"])
 
-    sweep = density_sweep()
+    plane = registry.resolve_backend()
+    if plane == "xla":
+        print("attention mask-density sweep: left out — it times the Pallas "
+              "tile kernels, which the xla plane does not have (run on a TPU "
+              "or under REPRO_KERNELS=interpret)")
+        return rows
+    sweep = density_sweep(plane)
     print_table("attention mask-density sweep (blocksparse vs dense-masked, "
-                f"L={SWEEP_L}, {SWEEP_BLOCK}x{SWEEP_BLOCK} tiles, interpret "
+                f"L={SWEEP_L}, {SWEEP_BLOCK}x{SWEEP_BLOCK} tiles, {plane} "
                 "plane)", sweep,
                 ["L", "mode", "density", "live_tiles", "tiles", "seconds",
                  "seconds_dense_masked", "speedup", "tokens_per_s",

@@ -141,14 +141,11 @@ def _synthesize(op: str, dims: dict, dtype: str):
                             dtype),
                 jnp.asarray(rng.standard_normal((dims["k"], dims["n"])),
                             dtype))
-    if op in ("spmv_ell", "spmm_ell"):
+    if op == "spmm_ell":
         rows, width = dims["rows"], dims["width"]
         vals = jnp.asarray(rng.standard_normal((rows, width)), dtype)
         cols = jnp.asarray(rng.integers(0, rows, (rows, width)), jnp.int32)
-        if op == "spmv_ell":
-            xv = jnp.asarray(rng.standard_normal(rows), dtype)
-        else:
-            xv = jnp.asarray(rng.standard_normal((rows, dims["rhs"])), dtype)
+        xv = jnp.asarray(rng.standard_normal((rows, dims["rhs"])), dtype)
         return (vals, cols, xv)
     return None
 
@@ -184,7 +181,7 @@ def main(mesh_shapes: Iterable = MESH_SHAPES, only: Optional[str] = None,
          tiny: bool = False, include_interpret: bool = False) -> list[dict]:
     import jax
 
-    from repro.core import ExecLevel, compat, costmodel, registry, use_level
+    from repro.core import ExecLevel, costmodel, registry, use_level
     from repro.core import blocking
 
     avail = jax.device_count()
@@ -204,7 +201,9 @@ def main(mesh_shapes: Iterable = MESH_SHAPES, only: Optional[str] = None,
     cases = _cases(tiny)
     if only:
         cases = {k: v for k, v in cases.items() if k == only}
-    kernel_plane = "pallas" if jax.default_backend() == "tpu" else "interpret"
+    # the kernel plane whose blocks are tuned is the registry's, never a
+    # silent substitute: 'pallas' on TPU, 'interpret' only when requested
+    kernel_plane = registry.resolve_backend()
 
     rows: list[dict] = []
     for label, spec in shapes:
@@ -213,8 +212,9 @@ def main(mesh_shapes: Iterable = MESH_SHAPES, only: Optional[str] = None,
         else:
             axes = tuple(a for a, _ in spec)
             sizes = tuple(s for _, s in spec)
-            mesh = compat.make_mesh(sizes, axes,
-                                    devices=jax.devices()[:int(np.prod(sizes))])
+            mesh = jax.make_mesh(sizes, axes,
+                                 (jax.sharding.AxisType.Auto,) * len(axes),
+                                 devices=jax.devices()[:int(np.prod(sizes))])
             level = ExecLevel.O4 if "pod" in axes else ExecLevel.O3
             ctx_mgr = use_level(level, mesh)
         with ctx_mgr:
@@ -244,7 +244,7 @@ def main(mesh_shapes: Iterable = MESH_SHAPES, only: Optional[str] = None,
                             "predicted": rec.get("predicted_seconds", ""),
                             "note": ""})
             if spec is not None and blocking.autotune_enabled() \
-                    and "matmul" in cases:
+                    and kernel_plane != "xla" and "matmul" in cases:
                 # drive the blocked chip kernel through the mesh variant
                 # once so the traced per-shard resolve default-marks its
                 # mesh-scoped key, then upgrade all pending entries eagerly

@@ -48,6 +48,7 @@ rows print as warnings.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -132,9 +133,18 @@ def main(argv=None) -> int:
 
     import jax
     from repro.core import registry
+    from repro.utils import roofline
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     ctx = registry.select_context()
+    kind = jax.devices()[0].device_kind
     meta = {"platform": jax.default_backend(), "jax": jax.__version__,
+            "device_kind": kind,
+            # the published peaks every rate on this device is read against
+            # (an unknown TPU kind raises rather than borrowing another's)
+            "peaks": (dataclasses.asdict(roofline.peaks(kind))
+                      if jax.default_backend() == "tpu" else None),
             "backend": registry.resolve_backend(),
             "device_count": jax.device_count(),
             # the ambient mesh (usually none at the CLI) and its axis roles,
@@ -232,7 +242,7 @@ def main(argv=None) -> int:
         "spgemm": lambda: spgemm.main(args.full),
         "attention": lambda: attention.main(args.full),
         "serve": lambda: serve.main(args.full),
-        "roofline": lambda: _roofline(roofline_table),
+        "roofline": roofline_table.main,
     }
     if args.only:
         suites = {args.only: suites[args.only]}
@@ -245,9 +255,6 @@ def main(argv=None) -> int:
         try:
             rows = fn()
             entry = {"status": "ok", "rows": rows}
-        except FileNotFoundError as e:
-            print(f"[{name}] skipped: {e}")
-            entry = {"status": "skipped", "error": str(e)}
         except Exception as e:                       # keep the run alive:
             print(f"[{name}] FAILED: {type(e).__name__}: {e}")
             entry = {"status": "error",
@@ -263,15 +270,6 @@ def main(argv=None) -> int:
     print("\nbenchmarks complete" + (f" ({len(failed)} suite(s) failed: "
                                      f"{', '.join(failed)})" if failed else ""))
     return 1 if failed else 0
-
-
-def _roofline(mod):
-    try:
-        return mod.main()
-    except FileNotFoundError:
-        print("roofline table: run launch/dryrun.py first "
-              "(results/dryrun_baseline.jsonl missing)")
-        return None
 
 
 if __name__ == "__main__":

@@ -148,7 +148,7 @@ def main(mesh_shapes: Iterable = MESH_SHAPES,
          only: Optional[str] = None) -> list[dict]:
     import jax
 
-    from repro.core import ExecLevel, compat, use_level
+    from repro.core import ExecLevel, use_level
 
     avail = jax.device_count()
     shapes = [(label, spec) for label, spec in mesh_shapes
@@ -174,8 +174,9 @@ def main(mesh_shapes: Iterable = MESH_SHAPES,
             axes = tuple(a for a, _ in spec)
             sizes = tuple(s for _, s in spec)
             devices = int(np.prod(sizes))
-            mesh = compat.make_mesh(sizes, axes,
-                                    devices=jax.devices()[:devices])
+            mesh = jax.make_mesh(sizes, axes,
+                                 (jax.sharding.AxisType.Auto,) * len(axes),
+                                 devices=jax.devices()[:devices])
             level = ExecLevel.O4 if "pod" in axes else ExecLevel.O3
             ctx = use_level(level, mesh)
         with ctx:

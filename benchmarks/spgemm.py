@@ -67,7 +67,7 @@ def run(full: bool = False) -> list[dict]:
     import jax
 
     from repro import sparse as S
-    from repro.core import ExecLevel, compat, registry, use_level
+    from repro.core import ExecLevel, registry, use_level
     from repro.sparse.spgemm import spgemm_symbolic
 
     n = N if full else N // 2
@@ -109,8 +109,9 @@ def run(full: bool = False) -> list[dict]:
             axes = tuple(x for x, _ in spec)
             sizes = tuple(s for _, s in spec)
             devices = int(np.prod(sizes))
-            mesh = compat.make_mesh(sizes, axes,
-                                    devices=jax.devices()[:devices])
+            mesh = jax.make_mesh(sizes, axes,
+                                 (jax.sharding.AxisType.Auto,) * len(axes),
+                                 devices=jax.devices()[:devices])
             level = ExecLevel.O4 if "pod" in axes else ExecLevel.O3
             with use_level(level, mesh):
                 variant = registry.select("spgemm", a, b).name

@@ -26,7 +26,7 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat, execlevel, sharding as shrules
+from repro.core import execlevel, sharding as shrules
 from repro.core.containers import Dense, unwrap
 
 __all__ = ["call", "capture", "emap", "Closure", "CallClosure"]
@@ -131,7 +131,7 @@ class CallClosure:
             sh = shrules.auto_sharding(arr.shape, mesh)
             arr = jax.device_put(arr, sh)
             placed.append(Dense(arr) if isinstance(a, Dense) else arr)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             return self._get_executable(self._retarget_key(ctx, mesh))(*placed)
 
     def lower(self, *args: Any):

@@ -32,7 +32,6 @@ from typing import Iterator, Optional
 
 import jax
 
-from repro.core import compat
 
 __all__ = ["ExecLevel", "ExecContext", "use_level", "current", "default_mesh_for"]
 
@@ -80,10 +79,12 @@ def default_mesh_for(level: ExecLevel) -> Optional[jax.sharding.Mesh]:
     n = int(os.environ.get("ARBB_NUM_CORES", len(devices)))
     n = max(1, min(n, len(devices)))
     if level == ExecLevel.O3:
-        return compat.make_mesh((n, 1), ("data", "model"))
+        return jax.make_mesh((n, 1), ("data", "model"),
+                             (jax.sharding.AxisType.Auto,) * 2)
     # O4: split off a pod axis when device count allows.
     pods = 2 if n % 2 == 0 and n >= 2 else 1
-    return compat.make_mesh((pods, n // pods, 1), ("pod", "data", "model"))
+    return jax.make_mesh((pods, n // pods, 1), ("pod", "data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 3)
 
 
 @contextlib.contextmanager

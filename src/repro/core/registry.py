@@ -43,11 +43,13 @@ Selection rules (DESIGN.md §6):
        Scope outranks the plane request: under an active mesh a sharded
        formulation beats any single-chip kernel, exactly as ArBB O3 beats
        O2 without the program text changing.
-    3. A requested plane that is unavailable (e.g. 'pallas' off-TPU)
-       degrades gracefully: selection falls through to the best available
-       variant — the same program text, retargeted.  Symmetrically, a
-       mesh-scoped variant without an ambient mesh (or whose shapes don't
-       divide the mesh) degrades to the chip formulation.
+    3. A requested plane that this hardware cannot run (e.g. 'pallas'
+       off-TPU) is an error: a run that asked for the chip must not
+       quietly measure or validate another plane.  A *variant* whose
+       accepts() rejects the arguments still degrades to the next one (the
+       ``dispatch.falloff`` counter records it), and a mesh-scoped variant
+       without an ambient mesh (or whose shapes don't divide the mesh)
+       degrades to the chip formulation.
 
 Providers register lazily: ops are declared here by module path and imported
 on first dispatch, so upper layers (models, serve) depend only on this
@@ -294,13 +296,22 @@ def use_backend(name: str) -> Iterator[str]:
         _state.plane = prev
 
 
+def _check_request(req: Optional[str], ctx: SelectContext) -> None:
+    """Raise when the requested plane cannot run on this hardware."""
+    if req is not None and not _plane_available(req, ctx):
+        raise RuntimeError(
+            f"backend plane {req!r} was requested but the platform is "
+            f"{ctx.platform!r}; it needs a TPU")
+
+
 def resolve_backend() -> str:
-    """The plane dispatch will favour right now: the requested plane when it
-    is available on this hardware, else the platform default ('pallas' on
-    TPU, 'xla' elsewhere).  A 'pallas' request off-TPU resolves to 'xla'."""
+    """The plane dispatch will favour right now: the requested plane, else
+    the platform default ('pallas' on TPU, 'xla' elsewhere).  A request this
+    hardware cannot run (e.g. 'pallas' off-TPU) raises."""
     ctx = select_context()
     req = requested_backend()
-    if req in PLANES and _plane_available(req, ctx):
+    _check_request(req, ctx)
+    if req is not None:
         return req
     return "pallas" if ctx.platform == "tpu" else "xla"
 
@@ -442,6 +453,7 @@ class OperatorRegistry:
         degradation fall-off: ring→chip, 2-D→1-D, pallas→xla)."""
         ctx = select_context()
         req = requested_backend()
+        _check_request(req, ctx)
         table = self._table(op)
         ranked, _ = self._ranked(op, args, kwargs, ctx, req, table)
         for i, v in enumerate(ranked):

@@ -57,7 +57,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import registry
@@ -197,9 +196,9 @@ def _ring_exec(plan: RingPlan, causal: bool, zigzag: bool, plane: str,
         return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(ql.dtype)
 
     spec = P(None, None, entry, None)
-    return jax.jit(shard_map(run, mesh=plan.mesh,
-                             in_specs=(spec, spec, spec), out_specs=spec,
-                             check_rep=False))
+    return jax.jit(jax.shard_map(run, mesh=plan.mesh,
+                                 in_specs=(spec, spec, spec), out_specs=spec,
+                                 check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -359,11 +358,11 @@ def _paged_ring_exec(plan: RingPlan, plane: str):
         return out.astype(q.dtype)
 
     rep = P(None, None, None, None)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         run, mesh=plan.mesh,
         in_specs=(rep, P(entry, None, None, None),
                   P(entry, None, None, None), P(None, None), P(None)),
-        out_specs=rep, check_rep=False))
+        out_specs=rep, check_vma=False))
 
 
 def paged_ring_attention(q, kpages, vpages, table, lens):

@@ -53,7 +53,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import registry
@@ -188,9 +187,9 @@ def _spmv_exec(plan: ReducePlan, kind: str, static):
     def run(xf, *loc):
         return local_fn(loc, xf)
 
-    return jax.jit(shard_map(run, mesh=plan.mesh,
-                             in_specs=(P(),) + _spmv_specs(entry)[kind],
-                             out_specs=P(entry), check_rep=False))
+    return jax.jit(jax.shard_map(run, mesh=plan.mesh,
+                                 in_specs=(P(),) + _spmv_specs(entry)[kind],
+                                 out_specs=P(entry), check_vma=False))
 
 
 def mesh_spmv(a, invec, **_: Any) -> Dense:
@@ -272,9 +271,9 @@ def _spmm_exec(plan: ReducePlan, kind: str, static):
     def run(xf, *loc):
         return local_fn(loc, xf)
 
-    return jax.jit(shard_map(run, mesh=plan.mesh,
-                             in_specs=(P(),) + _spmv_specs(entry)[kind],
-                             out_specs=P(entry, None), check_rep=False))
+    return jax.jit(jax.shard_map(run, mesh=plan.mesh,
+                                 in_specs=(P(),) + _spmv_specs(entry)[kind],
+                                 out_specs=P(entry, None), check_vma=False))
 
 
 def mesh_spmm(a, x, **_: Any) -> Dense:
@@ -342,10 +341,10 @@ def _spgemm_exec(plan: CannonPlan, ncpad: int):
         return plan.reduce_partials(part, scatter_dimension=0) \
             .astype(av.dtype)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=plan.mesh,
         in_specs=(P(), P(), P(pair_entry), P(pair_entry), P(pair_entry)),
-        out_specs=P(row_entry, None, None), check_rep=False))
+        out_specs=P(row_entry, None, None), check_vma=False))
 
 
 def mesh_spgemm(a, b, **_: Any):
@@ -433,10 +432,10 @@ def _matmul_exec(plan: ReducePlan, plane: str, blocks):
                                  block_k=block_k)
         return plan.psum_scatter(part, scatter_dimension=0)
 
-    return jax.jit(shard_map(local, mesh=plan.mesh,
-                             in_specs=(P(None, kentry), P(kentry, None)),
-                             out_specs=P(plan.data_spec_entry(), None),
-                             check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=plan.mesh,
+                                 in_specs=(P(None, kentry), P(kentry, None)),
+                                 out_specs=P(plan.data_spec_entry(), None),
+                                 check_vma=False))
 
 
 def mesh_matmul(a, b, *, block_m=None, block_n=None, block_k=None):
@@ -481,10 +480,10 @@ def _matmul2d_exec(plan: ReducePlan, model_axes: tuple, plane: str, blocks):
                                  block_k=block_k)
         return plan.psum_scatter(part, scatter_dimension=0)
 
-    return jax.jit(shard_map(local, mesh=plan.mesh,
-                             in_specs=(P(None, kentry), P(kentry, mentry)),
-                             out_specs=P(plan.data_spec_entry(), mentry),
-                             check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=plan.mesh,
+                                 in_specs=(P(None, kentry), P(kentry, mentry)),
+                                 out_specs=P(plan.data_spec_entry(), mentry),
+                                 check_vma=False))
 
 
 def _model_axes(plan: ReducePlan) -> tuple:
@@ -626,9 +625,9 @@ def _fft_exec(plan: ReducePlan):
         n = x.shape[0]
         # A[i1, i2] = x[i1 + n1*i2], row-sharded over the data subgrid
         a = jnp.reshape(x, (n // n1, n1)).T
-        c = shard_map(local, mesh=plan.mesh,
-                      in_specs=(P(turn_axis, None), P(turn_axis, None)),
-                      out_specs=P(None, turn_axis), check_rep=False)(a, tw)
+        c = jax.shard_map(local, mesh=plan.mesh,
+                          in_specs=(P(turn_axis, None), P(turn_axis, None)),
+                          out_specs=P(None, turn_axis), check_vma=False)(a, tw)
         # X[n2*k1 + k2] = C[k1, k2]: row-major flatten is the output order
         return jnp.reshape(c, (n,)).astype(x.dtype)
 
@@ -703,9 +702,10 @@ def _cg_exec(plan: ReducePlan, kind: str, static, max_iters: int):
         x, r, p, r2, k = jax.lax.while_loop(cond, body, init)
         return x, r2, k
 
-    return jax.jit(shard_map(run, mesh=plan.mesh,
-                             in_specs=(P(), P(entry)) + _spmv_specs(entry)[kind],
-                             out_specs=(P(entry), P(), P()), check_rep=False))
+    return jax.jit(jax.shard_map(
+        run, mesh=plan.mesh,
+        in_specs=(P(), P(entry)) + _spmv_specs(entry)[kind],
+        out_specs=(P(entry), P(), P()), check_vma=False))
 
 
 def cg_mesh(a, bv: jax.Array, *, stop, max_iters: int, mesh=None,

@@ -13,7 +13,6 @@ from typing import Any, Optional, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import compat
 
 __all__ = ["active_mesh", "batch_axes", "bspec", "constrain", "spec",
            "named", "MODEL"]
@@ -22,7 +21,7 @@ MODEL = "model"
 
 
 def active_mesh() -> Optional[Any]:
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     return None if m is None or m.empty else m
 
 

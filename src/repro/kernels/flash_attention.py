@@ -44,11 +44,12 @@ K grid is *bounded* per Q row by the compiled row extents instead of
 launching every above-diagonal step and ``pl.when``-ing it off
 (``row_extents=False`` keeps the legacy grid reachable for A/B parity).
 
-Like the BSR SpMM kernel, index arrays ride as whole-array VMEM refs and
-K/V sit whole per (batch, kv-head) in VMEM; on TPU hardware the production
-form hoists rowp/cols into scalar prefetch (``pltpu.PrefetchScalarGridSpec``)
-and double-buffers K/V tile DMAs — correctness here is validated in
-interpret mode against the masked oracle (kernels/ref.py).
+The layout's index arrays (rowp/mid/prowp/cols) and the paged-decode
+``kv_len`` ride in scalar memory (``pltpu.PrefetchScalarGridSpec``), where
+loop bounds and index maps can read them; K/V sit whole per (batch,
+kv-head) in VMEM and each live tile is read from the ref with ``pl.ds``.
+The running (m, l) state is kept as (bq, 1) columns — the layout the row
+reductions produce — and leaves the kernel as (batch, heads, seq_q, 1).
 """
 from __future__ import annotations
 
@@ -59,7 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
 
 __all__ = ["flash_attention_kernel", "flash_attention_state_kernel",
            "flash_attention_lens_kernel", "flash_attention_lens_state_kernel",
@@ -101,17 +101,18 @@ def merge_states(a, b):
 def _fa_step(
     q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
     *, scale: float, causal: bool, block_q: int, block_k: int,
-    lens_ref=None,
+    kv_len=None,
 ):
     """One grid step of the online-softmax recurrence: init the (m, l, acc)
     scratch on the first K panel, then fold this panel in (shared by the
     plain and the state-returning kernels).
 
-    ``lens_ref`` (a (1,) int32 block indexed by batch) is the paged-decode
-    prefix mask (DESIGN.md §13): keys at ``kpos >= lens_ref[0]`` are dead.
-    A row with *no* live key anywhere leaves ``m == NEG_INF`` — its (o, m,
-    l) is garbage, but the ring/state merge weights it by ``exp(m - m_g)``
-    which underflows to exactly 0, so empty shards/slots cancel."""
+    ``kv_len`` (this batch row's scalar, read from scalar memory) is the
+    paged-decode prefix mask (DESIGN.md §13): keys at ``kpos >= kv_len``
+    are dead.  A row with *no* live key anywhere leaves ``m == NEG_INF`` —
+    its (o, m, l) is garbage, but the ring/state merge weights it by
+    ``exp(m - m_g)`` which underflows to exactly 0, so empty shards/slots
+    cancel."""
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
@@ -129,26 +130,36 @@ def _fa_step(
         q = q_ref[0, 0]                                   # (bq, d)
         k = k_ref[0, 0]                                   # (bk, d)
         v = v_ref[0, 0]                                   # (bk, d)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
         if causal:
             qpos = iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             kpos = ik * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(qpos >= kpos, s, NEG_INF)
-        if lens_ref is not None:
+        if kv_len is not None:
             kpos = ik * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos < lens_ref[0], s, NEG_INF)
+            s = jnp.where(kpos < kv_len, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_ref[...]                               # (bq, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
+        p = jnp.exp(s - m_cur)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[...] = m_cur
+
+
+def _flush(o_ref, m_ref, l_ref, acc_ref, ms_ref=None, ls_ref=None):
+    """Normalise the accumulator into ``o`` (and emit (m, l) when asked)."""
+    o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                   ).astype(o_ref.dtype)
+    if ms_ref is not None:
+        ms_ref[0, 0] = m_ref[...]
+        ls_ref[0, 0] = l_ref[...]
 
 
 def flash_attention_kernel(
@@ -159,9 +170,8 @@ def flash_attention_kernel(
              causal=causal, block_q=block_q, block_k=block_k)
 
     @pl.when(pl.program_id(3) == kv_steps - 1)
-    def _flush():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+    def _():
+        _flush(o_ref, m_ref, l_ref, acc_ref)
 
 
 def flash_attention_state_kernel(
@@ -173,51 +183,45 @@ def flash_attention_state_kernel(
              causal=causal, block_q=block_q, block_k=block_k)
 
     @pl.when(pl.program_id(3) == kv_steps - 1)
-    def _flush():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
-        ms_ref[0, 0] = m_ref[...]
-        ls_ref[0, 0] = l_ref[...]
+    def _():
+        _flush(o_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref)
 
 
 def flash_attention_lens_kernel(
-    q_ref, k_ref, v_ref, lens_ref, o_ref, m_ref, l_ref, acc_ref,
+    lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     *, scale: float, causal: bool, kv_steps: int, block_q: int, block_k: int,
 ):
-    """Dense-grid kernel with a per-batch key-prefix mask (``lens_ref``):
-    only keys at positions ``< lens_ref[0]`` are live.  This is the paged
-    decode / chunked-prefill read path (DESIGN.md §13), where the K/V
-    operand is a gathered page view whose valid length varies per slot."""
+    """Dense-grid kernel with a per-batch key-prefix mask: only keys at
+    positions ``< lens_ref[b]`` are live (``lens_ref`` is scalar-prefetched).
+    This is the paged decode / chunked-prefill read path (DESIGN.md §13),
+    where the K/V operand is a gathered page view whose valid length varies
+    per slot."""
     _fa_step(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale=scale,
              causal=causal, block_q=block_q, block_k=block_k,
-             lens_ref=lens_ref)
+             kv_len=lens_ref[pl.program_id(0)])
 
     @pl.when(pl.program_id(3) == kv_steps - 1)
-    def _flush():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+    def _():
+        _flush(o_ref, m_ref, l_ref, acc_ref)
 
 
 def flash_attention_lens_state_kernel(
-    q_ref, k_ref, v_ref, lens_ref, o_ref, ms_ref, ls_ref, m_ref, l_ref,
+    lens_ref, q_ref, k_ref, v_ref, o_ref, ms_ref, ls_ref, m_ref, l_ref,
     acc_ref,
     *, scale: float, causal: bool, kv_steps: int, block_q: int, block_k: int,
 ):
     """Prefix-masked recurrence; the flush also emits the final (m, l)."""
     _fa_step(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale=scale,
              causal=causal, block_q=block_q, block_k=block_k,
-             lens_ref=lens_ref)
+             kv_len=lens_ref[pl.program_id(0)])
 
     @pl.when(pl.program_id(3) == kv_steps - 1)
-    def _flush():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
-        ms_ref[0, 0] = m_ref[...]
-        ls_ref[0, 0] = l_ref[...]
+    def _():
+        _flush(o_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref)
 
 
 def _fa_tiles_scan(
-    iq, q, k, v, rowp_ref, mid_ref, prowp_ref, cols_ref, bias_ref,
+    iq, q, k_ref, v_ref, rowp_ref, mid_ref, prowp_ref, cols_ref, bias_ref,
     *, scale, band, block_q: int, block_k: int,
 ):
     """Walk one Q row's live K tiles — the FULL loop (no masking), then the
@@ -227,7 +231,8 @@ def _fa_tiles_scan(
     the accumulator swapped for the online-softmax recurrence of
     :func:`_fa_step`; ``band`` is the compiled ``(causal, window, offset)``
     of positional specs (edge tiles masked by one iota compare) or None
-    (edge tiles add their stored bias tile)."""
+    (edge tiles add their stored bias tile).  Each live K/V tile is read
+    straight from the (batch, kv-head) ref at ``pl.ds(c * block_k)``."""
     d = q.shape[-1]
     start = rowp_ref[iq]
     midp = mid_ref[iq]
@@ -236,9 +241,11 @@ def _fa_tiles_scan(
     def fold(p, carry, *, masked: bool):
         m_prev, l_prev, acc = carry
         c = cols_ref[p]
-        kb = jax.lax.dynamic_slice(k, (c * block_k, 0), (block_k, d))
-        vb = jax.lax.dynamic_slice(v, (c * block_k, 0), (block_k, d))
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
+        kv_rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
+        kb = k_ref[0, 0, kv_rows, :]
+        vb = v_ref[0, 0, kv_rows, :]
+        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
         if masked:
             if band is not None:
                 causal, window, off = band
@@ -254,17 +261,17 @@ def _fa_tiles_scan(
                     s = jnp.where(live, s, NEG_INF)
             else:
                 pidx = prowp_ref[iq] + (p - midp)
-                s = s + bias_ref[pl.dslice(pidx, 1), :, :][0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+                s = s + bias_ref[pidx]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        pmat = jnp.exp(s - m_cur[:, None])
-        l_cur = l_prev * alpha + jnp.sum(pmat, axis=1)
-        acc = acc * alpha[:, None] + jnp.dot(
-            pmat.astype(v.dtype), vb, preferred_element_type=jnp.float32)
+        pmat = jnp.exp(s - m_cur)
+        l_cur = l_prev * alpha + jnp.sum(pmat, axis=1, keepdims=True)
+        acc = acc * alpha + jnp.dot(
+            pmat.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
         return m_cur, l_cur, acc
 
-    carry = (jnp.full((block_q,), NEG_INF, jnp.float32),
-             jnp.zeros((block_q,), jnp.float32),
+    carry = (jnp.full((block_q, 1), NEG_INF, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32),
              jnp.zeros((block_q, d), jnp.float32))
     carry = jax.lax.fori_loop(
         start, midp, functools.partial(fold, masked=False), carry)
@@ -280,11 +287,10 @@ def flash_attention_tiles_kernel(
     """One Q row per grid step; K grid replaced by the row's live-tile span.
     Fully-dead rows (start == stop) fall through with l = 0 → output 0."""
     m, l, acc = _fa_tiles_scan(
-        pl.program_id(2), q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
+        pl.program_id(2), q_ref[0, 0], k_ref, v_ref,
         rowp_ref, mid_ref, prowp_ref, cols_ref, bias_ref,
         scale=scale, band=band, block_q=block_q, block_k=block_k)
-    denom = jnp.maximum(l, 1e-30)
-    o_ref[0, 0] = (acc / denom[:, None]).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def flash_attention_tiles_state_kernel(
@@ -294,13 +300,30 @@ def flash_attention_tiles_state_kernel(
 ):
     """Same walk; the flush also emits the (m, l) state for ring merging."""
     m, l, acc = _fa_tiles_scan(
-        pl.program_id(2), q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
+        pl.program_id(2), q_ref[0, 0], k_ref, v_ref,
         rowp_ref, mid_ref, prowp_ref, cols_ref, bias_ref,
         scale=scale, band=band, block_q=block_q, block_k=block_k)
-    denom = jnp.maximum(l, 1e-30)
-    o_ref[0, 0] = (acc / denom[:, None]).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     ms_ref[0, 0] = m
     ls_ref[0, 0] = l
+
+
+def _state_outputs(q, block_q, index_map):
+    """Output shapes/specs of ``o`` plus the (m, l) state.  The state leaves
+    the kernel as (batch, heads, seq_q, 1) columns; callers see
+    (batch, heads, seq_q) after :func:`_squeeze_state`."""
+    batch, q_heads, seq_q, d = q.shape
+    o_spec = pl.BlockSpec((1, 1, block_q, d), index_map)
+    state_spec = pl.BlockSpec((1, 1, block_q, 1), index_map)
+    state_shape = jax.ShapeDtypeStruct((batch, q_heads, seq_q, 1),
+                                       jnp.float32)
+    return ((jax.ShapeDtypeStruct(q.shape, q.dtype), state_shape,
+             state_shape), (o_spec, state_spec, state_spec))
+
+
+def _squeeze_state(out):
+    o, m, l = out
+    return o, m[..., 0], l[..., 0]
 
 
 def flash_attention_tiles(
@@ -341,40 +364,41 @@ def flash_attention_tiles(
         else flash_attention_tiles_kernel,
         scale=scale, band=layout.band, block_q=bq, block_k=bk)
 
-    npart = layout.biases.shape[0]
-    o_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, iq: (b, h, iq, 0))
-    out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
-    out_specs = o_spec
-    if return_state:
-        state_spec = pl.BlockSpec((1, 1, bq), lambda b, h, iq: (b, h, iq))
-        state_shape = jax.ShapeDtypeStruct((batch, q_heads, seq_q),
-                                           jnp.float32)
-        out_shape = (out_shape, state_shape, state_shape)
-        out_specs = (o_spec, state_spec, state_spec)
+    def q_map(b, h, iq, *_):
+        return (b, h, iq, 0)
 
-    return pl.pallas_call(
-        kernel,
+    def kv_map(b, h, iq, *_):
+        return (b, h // group, 0, 0)
+
+    if return_state:
+        out_shape, out_specs = _state_outputs(q, bq, q_map)
+    else:
+        out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+        out_specs = pl.BlockSpec((1, 1, bq, d), q_map)
+    npart = layout.biases.shape[0]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
         grid=(batch, q_heads, nq),
         in_specs=[
-            pl.BlockSpec((nq + 1,), lambda b, h, iq: (0,)),
-            pl.BlockSpec((nq,), lambda b, h, iq: (0,)),
-            pl.BlockSpec((nq,), lambda b, h, iq: (0,)),
-            pl.BlockSpec((layout.ntiles,), lambda b, h, iq: (0,)),
-            pl.BlockSpec((npart, bq, bk), lambda b, h, iq: (0, 0, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, seq_k, d),
-                         lambda b, h, iq: (b, h // group, 0, 0)),
-            pl.BlockSpec((1, 1, seq_k, d),
-                         lambda b, h, iq: (b, h // group, 0, 0)),
+            pl.BlockSpec((npart, bq, bk), lambda b, h, iq, *_: (0, 0, 0)),
+            pl.BlockSpec((1, 1, bq, d), q_map),
+            pl.BlockSpec((1, 1, seq_k, d), kv_map),
+            pl.BlockSpec((1, 1, seq_k, d), kv_map),
         ],
         out_specs=out_specs,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
-    )(layout.rowp, layout.mid, layout.prowp, layout.cols, layout.biases,
-      q, k, v)
+    )(jnp.asarray(layout.rowp), jnp.asarray(layout.mid),
+      jnp.asarray(layout.prowp), jnp.asarray(layout.cols),
+      jnp.asarray(layout.biases), q, k, v)
+    return _squeeze_state(out) if return_state else out
 
 
 def flash_attention(
@@ -423,57 +447,52 @@ def flash_attention(
             scale=scale, return_state=return_state, interpret=interpret)
 
     grid = (batch, q_heads, seq_q // block_q, seq_k // block_k)
+    base = {
+        (False, False): flash_attention_kernel,
+        (False, True): flash_attention_state_kernel,
+        (True, False): flash_attention_lens_kernel,
+        (True, True): flash_attention_lens_state_kernel,
+    }[(kv_len is not None, return_state)]
+    kernel = functools.partial(base, scale=scale, causal=causal,
+                               kv_steps=grid[3], block_q=block_q,
+                               block_k=block_k)
 
-    if kv_len is not None:
-        kernel = functools.partial(
-            flash_attention_lens_state_kernel if return_state
-            else flash_attention_lens_kernel,
-            scale=scale, causal=causal,
-            kv_steps=grid[3], block_q=block_q, block_k=block_k)
-    else:
-        kernel = functools.partial(
-            flash_attention_state_kernel if return_state
-            else flash_attention_kernel,
-            scale=scale, causal=causal,
-            kv_steps=grid[3], block_q=block_q, block_k=block_k)
+    # index maps take trailing scalar-prefetch refs (the lens) when present
+    def q_map(b, h, iq, ik, *_):
+        return (b, h, iq, 0)
 
-    o_spec = pl.BlockSpec((1, 1, block_q, d), lambda b, h, iq, ik: (b, h, iq, 0))
-    out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
-    out_specs = o_spec
+    def kv_map(b, h, iq, ik, *_):
+        return (b, h // group, ik, 0)
+
     if return_state:
-        state_spec = pl.BlockSpec((1, 1, block_q),
-                                  lambda b, h, iq, ik: (b, h, iq))
-        state_shape = jax.ShapeDtypeStruct((batch, q_heads, seq_q),
-                                           jnp.float32)
-        out_shape = (out_shape, state_shape, state_shape)
-        out_specs = (o_spec, state_spec, state_spec)
-
-    in_specs = [
-        o_spec,
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda b, h, iq, ik: (b, h // group, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda b, h, iq, ik: (b, h // group, ik, 0)),
-    ]
-    operands = (q, k, v)
-    if kv_len is not None:
-        in_specs.append(pl.BlockSpec((1,), lambda b, h, iq, ik: (b,)))
-        operands = (q, k, v, kv_len.astype(jnp.int32))
-
-    return pl.pallas_call(
-        kernel,
+        out_shape, out_specs = _state_outputs(q, block_q, q_map)
+    else:
+        out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+        out_specs = pl.BlockSpec((1, 1, block_q, d), q_map)
+    prefetch = () if kv_len is None else (kv_len.astype(jnp.int32),)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, d), q_map),
+            pl.BlockSpec((1, 1, block_k, d), kv_map),
+            pl.BlockSpec((1, 1, block_k, d), kv_map),
+        ],
         out_specs=out_specs,
-        out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
-    )(*operands)
+    )(*prefetch, q, k, v)
+    return _squeeze_state(out) if return_state else out

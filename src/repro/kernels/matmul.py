@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
-
 __all__ = ["matmul_kernel", "matmul"]
 
 
@@ -38,9 +36,12 @@ def matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
-    )
+    a, b = a_ref[...], b_ref[...]
+    # f32 operands get f32 products (the MXU's default is one bf16 pass)
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else None)
+    acc_ref[...] += jnp.dot(a, b, precision=precision,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -80,7 +81,7 @@ def matmul(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

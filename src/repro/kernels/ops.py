@@ -104,29 +104,21 @@ def matmul(a, b, *, block_m=None, block_n=None, block_k=None):
 # SpMV (ELL + DIA layouts)
 # ---------------------------------------------------------------------------
 
-def _ell_inner(values, cols, x, *, blocks, interpret):
-    return spmv_k.spmv_ell(values, cols, x, block_rows=blocks["rows"],
-                           block_width=blocks["width"], interpret=interpret)
-
-
-_ell_blocked = blocked(
-    "spmv_ell", _ell_inner,
-    pad={0: ("rows", "width"), 1: ("rows", "width")}, out=("rows",),
-    defaults={"rows": 8, "width": 128},
-    candidates=({"rows": 16}, {"rows": 32}, {"width": 256}),
-)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _spmv_ell_impl(values, cols, x, interpret):
+    return spmv_k.spmv_ell(values, cols, x, interpret=interpret)
 
 
 @registry.register("spmv_ell", "pallas", plane="pallas", cost=Cost.PALLAS,
-                   doc="padded block-ELL kernel (kernels/spmv.py)")
+                   doc="slot-major lane-gather ELL kernel (kernels/spmv.py)")
 def _spmv_ell_pallas(values, cols, x):
-    return _ell_blocked(values, cols, x, interpret=False)
+    return _spmv_ell_impl(values, cols, x, interpret=False)
 
 
 @registry.register("spmv_ell", "interpret", plane="interpret",
                    cost=Cost.INTERPRET)
 def _spmv_ell_interpret(values, cols, x):
-    return _ell_blocked(values, cols, x, interpret=True)
+    return _spmv_ell_impl(values, cols, x, interpret=True)
 
 
 _spmv_ell_ref_jit = jax.jit(ref.spmv_ell_ref)
@@ -139,6 +131,9 @@ def _spmv_ell_xla(values, cols, x):
 
 
 def spmv_ell(values, cols, x):
+    """ELL SpMV.  The ``pallas`` kernel's work grows with each slot's column
+    spread (DESIGN.md §2): run large matrices whose columns have no
+    locality under ``registry.use_backend("xla")``."""
     return registry.dispatch("spmv_ell", values, cols, x)
 
 
@@ -189,10 +184,10 @@ def _fft_stages(x, interpret):
     while i < n:
         stage_tw_re = jnp.tile(tw_re[:m], i)
         stage_tw_im = jnp.tile(tw_im[:m], i)
-        ore, oim = fft_k.fft_stage(re.reshape(n // 2, 2), im.reshape(n // 2, 2),
-                                   stage_tw_re, stage_tw_im,
-                                   interpret=interpret)
-        re, im = ore.reshape(n), oim.reshape(n)
+        re, im = fft_k.fft_stage(re.reshape(n // 2, 2).T,
+                                 im.reshape(n // 2, 2).T, stage_tw_re,
+                                 stage_tw_im, interpret=interpret)
+        re, im = re.reshape(n), im.reshape(n)
         m >>= 1
         i <<= 1
     return (re + 1j * im).astype(x.dtype)

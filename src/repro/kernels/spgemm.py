@@ -36,8 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.core import compat
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["spgemm_bsr_kernel", "spgemm_bsr"]
 
@@ -73,8 +72,7 @@ def spgemm_bsr_kernel(a_rowp_ref, a_cols_ref, a_vals_ref,
     def write(r, carry):
         j = c_cols_ref[r]
         tile = jax.lax.dynamic_slice(acc, (0, j * block), (block, block))
-        pl.store(o_ref, (pl.dslice(r, 1), slice(None), slice(None)),
-                 tile[None].astype(o_ref.dtype))
+        o_ref[pl.ds(r, 1), :, :] = tile[None].astype(o_ref.dtype)
         return carry
 
     jax.lax.fori_loop(c_rowp_ref[i], c_rowp_ref[i + 1], write, 0)
@@ -115,7 +113,7 @@ def spgemm_bsr(
         # (disjoint slots), so the revisited block is never double-written
         out_specs=pl.BlockSpec((nc, bs, bs), lambda i: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nc, bs, bs), a_vals.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -32,8 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.core import compat
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["spmm_ell_kernel", "spmm_ell", "spmm_bsr_kernel", "spmm_bsr"]
 
@@ -81,7 +80,7 @@ def spmm_ell(
         out_specs=pl.BlockSpec((block_rows, block_rhs),
                                lambda i, j, w: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nrows, k), values.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -95,13 +94,11 @@ def spmm_bsr_kernel(rowp_ref, cols_ref, values_ref, x_ref, o_ref, *,
     i = pl.program_id(0)
     start = rowp_ref[i]
     stop = rowp_ref[i + 1]
-    x = x_ref[...]                                # (n, bn) panel, VMEM
 
     def body(p, acc):
         blk = values_ref[pl.dslice(p, 1), :, :][0]          # (bs, bs)
         c = cols_ref[p]
-        xb = jax.lax.dynamic_slice(x, (c * block, 0),
-                                   (block, x.shape[1]))     # (bs, bn)
+        xb = x_ref[pl.ds(c * block, block), :]              # (bs, bn)
         return acc + jnp.dot(blk, xb, preferred_element_type=jnp.float32)
 
     acc = jax.lax.fori_loop(
@@ -140,7 +137,7 @@ def spmm_bsr(
         ],
         out_specs=pl.BlockSpec((bs, block_rhs), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nbrows * bs, k), values.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

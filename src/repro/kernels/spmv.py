@@ -1,29 +1,32 @@
-"""Pallas TPU kernel: SpMV in padded block-ELL layout (mod2as, TPU-native).
+"""Pallas TPU kernels: SpMV in ELL and DIA layouts (mod2as / CG, TPU-native).
 
 Hardware adaptation (DESIGN.md §2): the paper's CSR formulation (after Bell &
 Garland's CUDA kernels) is a per-row ragged gather loop — idiomatic for cache
 hierarchies and warp-per-row GPUs, hostile to the TPU vector unit (no cheap
 arbitrary gather, raggedness defeats tiling).  The TPU-native layout is
-**padded ELL**: ``values``/``cols`` as rectangular (nrows, width) arrays,
-width padded to the lane count (128).  The kernel walks (row_block, col_block)
-tiles; each step does
+**padded ELL**: ``values``/``cols`` as rectangular (nrows, width) arrays.
 
-    acc[r] += sum_w values[r, w] * x[cols[r, w]]
+The one gather the vector unit has is a lane gather inside a 128-lane vreg
+row (``jnp.take_along_axis(..., axis=1)`` on an (8k, 128) tile).  So the
+kernel reads ELL *slot-major*: slot ``w`` of 1024 consecutive rows is one
+(8, 128) tile, and those rows' columns of that slot sit in a narrow range of
+x for every matrix with locality (banded, stencil, reordered meshes).  x is
+viewed as (n/128, 128) *sub-panels*; for each (row chunk, slot) the host-free
+prologue computes the live sub-panel range ``[lo, hi]`` (scalar-prefetched),
+and the kernel folds in one lane gather per sub-panel of that range:
 
-with ``x`` held whole in VMEM (the paper's largest input, n = 10240 f32, is
-40 KiB — VMEM-resident with room to spare; for larger n the grid gains an
-x-panel dimension and cols are bucketed per panel — not needed for the paper's
-sweep).
+    g = Σ_{q=lo..hi} where(cols >> 7 == q, gather(x[q], cols & 127), 0)
+    y[chunk] += values[w, chunk] * g
 
-The in-kernel gather ``x[cols_tile]`` lowers to a Mosaic dynamic-gather on the
-sublane dim; on TPU generations without it, the documented fallback is the
-one-hot-matmul contraction (``dot(values * onehot(cols), x)``) which trades
-the gather for MXU work.  Correctness here is validated in interpret mode
-against :mod:`repro.kernels.ref` (exact CSR semantics).
+Padding entries (value 0) are left out of the ranges.  x stays whole in VMEM
+while it fits ``x_vmem_bytes``; beyond that it stays in HBM and each range
+is read in windows of ``_WINDOW`` sub-panels by DMA ("x in panels").  The
+cost per chunk is proportional to its column spread / 128, so a matrix with
+no locality (uniform random columns) pays n/128 gathers per chunk and slot.
 
 For the *banded* systems of the CG study (paper Table 2) the DIA kernel below
-removes the gather entirely: each diagonal contributes a shifted FMA, and the
-shift is a static lane rotation — the strongest form of the adaptation.
+removes the gather entirely: each diagonal contributes a shifted FMA over a
+row tile, reading an x window padded by one tile on either side.
 """
 from __future__ import annotations
 
@@ -34,22 +37,73 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
-
 __all__ = ["spmv_ell_kernel", "spmv_ell", "spmv_dia_kernel", "spmv_dia"]
 
+LANES = 128
+#: rows of one (8, 128) slot tile — the unit a sub-panel range covers
+CHUNK = 8 * LANES
+#: sub-panels per DMA window when x is held in HBM
+_WINDOW = 16
+#: default VMEM budget for holding x whole (v5e has 128 MiB of VMEM)
+X_VMEM_BYTES = 16 << 20
 
-def spmv_ell_kernel(values_ref, cols_ref, x_ref, o_ref, *, w_steps: int):
-    """One row-block; accumulates over width (w) grid dimension."""
-    @pl.when(pl.program_id(1) == 0)
-    def _zero():
-        o_ref[...] = jnp.zeros_like(o_ref)
 
-    vals = values_ref[...]                       # (bm, bw)
-    cols = cols_ref[...]                         # (bm, bw) int32
-    x = x_ref[...]                               # (n,) VMEM-resident
-    gathered = jnp.take(x, cols, axis=0)         # Mosaic dynamic gather
-    o_ref[...] += jnp.sum(vals * gathered, axis=1)
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def spmv_ell_kernel(lo_ref, hi_ref, vals_ref, cols_ref, x_ref, o_ref,
+                    *scratch, width: int, chunks: int):
+    """One row block of ``chunks`` (8, 128) row tiles, every ELL slot.
+
+    ``x_ref`` is x as (n/128, 128) sub-panels: whole in VMEM, or in HBM with
+    ``scratch = (window, sem)`` when it is read by DMA in ``_WINDOW``-row
+    windows."""
+    blk = pl.program_id(0)
+
+    def fold_range(cols, lo, hi, g):
+        def gather(q, xs, g):
+            local = cols - q * LANES
+            hit = (local >= 0) & (local < LANES)
+            got = jnp.take_along_axis(jnp.broadcast_to(xs, cols.shape),
+                                      jnp.where(hit, local, 0), axis=1)
+            return g + jnp.where(hit, got, 0.0)
+
+        if not scratch:
+            return jax.lax.fori_loop(
+                lo, hi + 1, lambda q, g: gather(q, x_ref[pl.ds(q, 1), :], g),
+                g)
+        win, sem = scratch
+
+        def window(t, g):
+            q0 = lo + t * _WINDOW
+            cp = pltpu.make_async_copy(x_ref.at[pl.ds(q0, _WINDOW)], win, sem)
+            cp.start()
+            cp.wait()
+            top = jnp.minimum(q0 + _WINDOW, hi + 1)
+            return jax.lax.fori_loop(
+                q0, top, lambda q, g: gather(q, win[pl.ds(q - q0, 1), :], g),
+                g)
+
+        nwin = jnp.maximum(hi - lo + _WINDOW, 0) // _WINDOW
+        return jax.lax.fori_loop(0, nwin, window, g)
+
+    def chunk(c, carry):
+        rows = pl.ds(pl.multiple_of(c * 8, 8), 8)
+
+        def slot(w, acc):
+            m = (blk * chunks + c) * width + w
+            cols = cols_ref[w, rows, :]
+            g = fold_range(cols, lo_ref[m], hi_ref[m],
+                           jnp.zeros(cols.shape, jnp.float32))
+            return acc + vals_ref[w, rows, :].astype(jnp.float32) * g
+
+        acc = jax.lax.fori_loop(0, width, slot,
+                                jnp.zeros((8, LANES), jnp.float32))
+        o_ref[rows, :] = acc.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, chunk, 0)
 
 
 def spmv_ell(
@@ -57,45 +111,90 @@ def spmv_ell(
     cols: jax.Array,
     x: jax.Array,
     *,
-    block_rows: int = 8,
-    block_width: int = 128,
+    block_rows: int = 8 * CHUNK,
+    x_vmem_bytes: int = X_VMEM_BYTES,
     interpret: bool = False,
 ) -> jax.Array:
-    """ELL SpMV: ``y[i] = sum_w values[i, w] * x[cols[i, w]]``."""
-    nrows, width = values.shape
-    assert cols.shape == (nrows, width)
-    assert nrows % block_rows == 0 and width % block_width == 0, (
-        (nrows, width), (block_rows, block_width))
-    grid = (nrows // block_rows, width // block_width)
+    """ELL SpMV: ``y[i] = sum_w values[i, w] * x[cols[i, w]]``.
 
-    return pl.pallas_call(
-        functools.partial(spmv_ell_kernel, w_steps=grid[1]),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, block_width), lambda i, w: (i, w)),
-            pl.BlockSpec((block_rows, block_width), lambda i, w: (i, w)),
-            pl.BlockSpec((x.shape[0],), lambda i, w: (0,)),  # x whole, VMEM
-        ],
-        out_specs=pl.BlockSpec((block_rows,), lambda i, w: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nrows,), values.dtype),
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"),
+    Any shapes: rows pad to ``block_rows`` (a multiple of 1024), x to whole
+    sub-panels.  x is held whole in VMEM up to ``x_vmem_bytes``, else read
+    from HBM in windows."""
+    nrows, width = values.shape
+    assert cols.shape == (nrows, width), (cols.shape, values.shape)
+    assert block_rows % CHUNK == 0, block_rows
+    block_rows = min(block_rows, _round_up(nrows, CHUNK))
+    npad = _round_up(nrows, block_rows)
+    chunks = block_rows // CHUNK
+    nblocks = npad // block_rows
+
+    # slot-major tiles: (width, npad/128, 128); padding rows hold value 0
+    vt = jnp.pad(values, ((0, npad - nrows), (0, 0))).T
+    ct = jnp.pad(cols.astype(jnp.int32), ((0, npad - nrows), (0, 0))).T
+    vt = vt.reshape(width, npad // LANES, LANES)
+    ct = ct.reshape(width, npad // LANES, LANES)
+
+    # live sub-panel range per (row chunk, slot); empty ranges are (big, -1)
+    sub = (ct >> 7).reshape(width, npad // CHUNK, CHUNK)
+    live = (vt != 0).reshape(sub.shape)
+    big = jnp.iinfo(jnp.int32).max // 2
+    lo = jnp.min(jnp.where(live, sub, big), axis=2).T.reshape(-1)
+    hi = jnp.max(jnp.where(live, sub, -1), axis=2).T.reshape(-1)
+
+    xrows = _round_up(x.shape[0], LANES) // LANES
+    in_vmem = xrows * LANES * 4 <= x_vmem_bytes
+    pad_rows = 0 if in_vmem else _WINDOW        # a window never runs off x
+    xp = jnp.pad(x.astype(jnp.float32),
+                 (0, (xrows + pad_rows) * LANES - x.shape[0]))
+    xp = xp.reshape(xrows + pad_rows, LANES)
+    if in_vmem:
+        x_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
+        scratch = []
+        vmem = xp.size * 4
+    else:
+        x_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = [pltpu.VMEM((_WINDOW, LANES), jnp.float32),
+                   pltpu.SemaphoreType.DMA(())]
+        vmem = _WINDOW * LANES * 4
+    tile = pl.BlockSpec((width, block_rows // LANES, LANES),
+                        lambda i, *_: (0, i, 0))
+    vmem += 4 * width * block_rows * 4 + 2 * block_rows * 4
+
+    y = pl.pallas_call(
+        functools.partial(spmv_ell_kernel, width=width, chunks=chunks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nblocks,),
+            in_specs=[tile, tile, x_spec],
+            out_specs=pl.BlockSpec((block_rows // LANES, LANES),
+                                   lambda i, *_: (i, 0)),
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct((npad // LANES, LANES), values.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem + (16 << 20),
         ),
         interpret=interpret,
-    )(values, cols, x)
+    )(lo, hi, vt, ct, xp)
+    return y.reshape(-1)[:nrows]
 
 
-def spmv_dia_kernel(diags_ref, xpad_ref, o_ref, *, offsets: tuple[int, ...],
-                    n: int, max_off: int):
-    """Banded SpMV: y = sum_d diags[d] * x[shifted by offsets[d]].
+def spmv_dia_kernel(diags_ref, xprev_ref, xcur_ref, xnext_ref, o_ref, win_ref,
+                    *, offsets: tuple[int, ...], tile: int):
+    """Banded SpMV over one row tile: y = sum_d diags[d] * x[row + off_d].
 
-    ``xpad`` is x zero-padded by max|offset| on both sides so every shifted
-    read is a *static slice* — no rotation, no gather, pure VPU FMAs."""
-    acc = jnp.zeros_like(o_ref)
+    The three x blocks are the tiles before, at and after this one; copied
+    side by side (lane-aligned stores) they form the window every shifted
+    read is a *static slice* of — no rotation, no gather, pure VPU FMAs."""
+    win_ref[:, 0:tile] = xprev_ref[...]
+    win_ref[:, tile:2 * tile] = xcur_ref[...]
+    win_ref[:, 2 * tile:3 * tile] = xnext_ref[...]
+    acc = jnp.zeros((1, tile), jnp.float32)
     for d, off in enumerate(offsets):            # static: unrolled in Mosaic
-        lo = max_off + off
-        acc += diags_ref[d, :] * xpad_ref[pl.dslice(lo, n)]
-    o_ref[...] = acc
+        acc += (diags_ref[pl.ds(d, 1), :].astype(jnp.float32)
+                * win_ref[:, pl.ds(tile + off, tile)])
+    o_ref[...] = acc.astype(o_ref.dtype)
 
 
 def spmv_dia(
@@ -105,20 +204,33 @@ def spmv_dia(
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """DIA (banded) SpMV.  diags: (ndiags, n) aligned per repro.numerics.sparse."""
+    """DIA (banded) SpMV.  diags: (ndiags, n) aligned per repro.numerics.sparse.
+
+    The grid walks row tiles of 8192 lanes, raised to cover max|offset|
+    and lowered for short vectors: a tile reads its own diagonals' columns
+    plus the x tiles on either side, so one tile must span max|offset|."""
     ndiags, n = diags.shape
     max_off = max((abs(o) for o in offsets), default=0)
-    xpad = jnp.pad(x, (max_off, max_off))
+    tile = _round_up(max(max_off, min(n, 8192)), LANES)
+    npad = _round_up(n, tile)
+    dp = jnp.pad(diags, ((0, 0), (0, npad - n)))
+    xpad = jnp.pad(x.astype(jnp.float32), (tile, npad - n + tile))[None]
 
-    return pl.pallas_call(
-        functools.partial(spmv_dia_kernel, offsets=tuple(offsets), n=n,
-                          max_off=max_off),
-        grid=(1,),
+    def x_block(shift):
+        return pl.BlockSpec((1, tile), lambda i: (0, i + shift))
+
+    y = pl.pallas_call(
+        functools.partial(spmv_dia_kernel, offsets=tuple(offsets), tile=tile),
+        grid=(npad // tile,),
         in_specs=[
-            pl.BlockSpec((ndiags, n), lambda i: (0, 0)),
-            pl.BlockSpec((n + 2 * max_off,), lambda i: (0,)),
+            pl.BlockSpec((ndiags, tile), lambda i: (0, i)),
+            x_block(0), x_block(1), x_block(2),
         ],
-        out_specs=pl.BlockSpec((n,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((n,), diags.dtype),
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, npad), diags.dtype),
+        scratch_shapes=[pltpu.VMEM((1, 3 * tile), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(diags, xpad)
+    )(dp, xpad, xpad, xpad)
+    return y[0, :n]

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import compat
 from repro.configs import get_config, list_configs
 from repro.configs.base import ModelConfig
 from repro.distributed.partition import (param_specs, data_axes, zero1_specs,
@@ -222,7 +221,7 @@ def run_cell(arch: str, shape_name: str, mesh, *, verbose: bool = True,
     else:
         jitted, args = build_decode(cfg, mesh, shape)
 
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = jitted.lower(*args)
         t_lower = clock() - t0
         t0 = clock()
@@ -284,7 +283,7 @@ def _probe_cost(cfg: ModelConfig, shape: ShapeSpec, mesh, depth: int,
         jitted, args = build_prefill(sub, mesh, shape)
     else:
         jitted, args = build_decode(sub, mesh, shape)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         compiled = jitted.lower(*args).compile()
     ca = compiled.cost_analysis()
     if not isinstance(ca, dict):
@@ -327,7 +326,7 @@ def corrected_terms(arch: str, shape_name: str, mesh, *,
                 if shape.kind != "decode" else shape.global_batch)
     mf = roofline.model_flops(cfg, n_tokens,
                               training=(shape.kind == "train"))
-    t_c, t_m, t_x = flops / hw.peak_flops, hbm / hw.hbm_bw, coll / hw.link_bw
+    t_c, t_m, t_x = flops / hw.peak_flops, hbm / hw.hbm_bw, coll / hw.ici_bw
     dom = max((t_c, "compute"), (t_m, "memory"), (t_x, "collective"))[1]
     return {
         "arch": arch, "shape": shape_name,

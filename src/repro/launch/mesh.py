@@ -19,7 +19,6 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh
 
-from repro.core import compat
 
 __all__ = ["make_production_mesh", "make_mesh", "describe"]
 
@@ -27,14 +26,17 @@ __all__ = ["make_production_mesh", "make_mesh", "describe"]
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         (jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(data: int = 1, model: int = 1, pod: Optional[int] = None) -> Mesh:
     """Arbitrary mesh for tests/smokes (sized to available devices)."""
     if pod:
-        return compat.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return compat.make_mesh((data, model), ("data", "model"))
+        return jax.make_mesh((pod, data, model), ("pod", "data", "model"),
+                             (jax.sharding.AxisType.Auto,) * 3)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
 
 
 def describe(mesh: Mesh) -> str:

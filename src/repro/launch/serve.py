@@ -24,6 +24,7 @@ from repro.core import ExecLevel, use_level
 from repro.launch.train import reduce_config
 from repro.models.lm import LM
 from repro.obs.trace import clock
+from repro.utils.compile_cache import enable_compile_cache
 from repro.serve import Engine, SamplingParams
 
 
@@ -43,6 +44,7 @@ def main(argv=None) -> int:
                          "prefill sequence over the ring (default: the "
                          "ambient level / ARBB_OPT_LEVEL)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.scale != 1.0:

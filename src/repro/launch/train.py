@@ -19,8 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import compat
 from repro.obs.trace import clock
+from repro.utils.compile_cache import enable_compile_cache
 from repro.checkpoint import Checkpointer
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
@@ -134,7 +134,7 @@ class Trainer:
         history = []
         start = int(jax.device_get(self.state.step))
         t0 = clock()
-        ctx = compat.set_mesh(self.mesh) if self.mesh is not None \
+        ctx = jax.sharding.set_mesh(self.mesh) if self.mesh is not None \
             else _nullcontext()
         with ctx:
             for i in range(start, steps):
@@ -181,6 +181,7 @@ def main(argv=None) -> int:
                     help="path to a text/binary file (byte-level LM); "
                          "default: synthetic tokens")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.scale != 1.0:

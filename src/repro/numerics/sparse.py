@@ -16,7 +16,7 @@ the containers hold device arrays.
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +24,7 @@ import numpy as np
 
 __all__ = ["CSR", "ELL", "DIA", "random_sparse", "banded_spd",
            "csr_from_dense", "ell_from_csr", "dia_from_dense",
-           "csr_row_ids"]
+           "csr_row_ids", "Stencil3D", "stencil_3d"]
 
 
 def csr_row_ids(rowp: jax.Array, count: int) -> jax.Array:
@@ -213,3 +213,80 @@ def banded_spd(n: int, bw: int, seed: int = 0, dtype=np.float64) -> np.ndarray:
     # strictly diagonally dominant diagonal
     a[np.arange(n), np.arange(n)] = np.abs(a).sum(axis=1) + 1.0
     return a
+
+
+class Stencil3D(NamedTuple):
+    """One 3-D stencil operator in the three layouts the kernels take."""
+    dia: DIA
+    ell: ELL
+    csr: CSR
+
+
+def stencil_3d(grid: int | tuple[int, int, int], *, points: int = 7,
+               seed: int = 0, dtype=np.float32) -> Stencil3D:
+    """Seeded variable-coefficient diffusion operator on an nx×ny×nz grid.
+
+    Rows are grid points, x fastest (``i = x + nx*(y + ny*z)``).  Each
+    stencil edge (the 6 face neighbours for ``points=7``, all 26 for
+    ``points=27``) carries a conductance ``c = 1 + U[0, 0.5)`` drawn from
+    ``seed``; ``A[i, j] = -c`` and ``A[i, i]`` sums the conductances of all
+    its stencil edges, including those leaving the grid (Dirichlet), so A
+    is symmetric, strictly diagonally dominant and positive definite.
+
+    DIA, ELL and CSR are built from the offsets directly — never through a
+    dense matrix — so chip-filling grids (128³ = 2,097,152 rows) are
+    cheap.  ELL rows list their entries by ascending column, with
+    out-of-grid neighbours as padding (value 0, column 0)."""
+    nx, ny, nz = (grid,) * 3 if isinstance(grid, int) else grid
+    if points == 7:
+        steps = [(-1, 0, 0), (1, 0, 0), (0, -1, 0), (0, 1, 0), (0, 0, -1),
+                 (0, 0, 1)]
+    elif points == 27:
+        steps = [(dx, dy, dz) for dz in (-1, 0, 1) for dy in (-1, 0, 1)
+                 for dx in (-1, 0, 1) if (dx, dy, dz) != (0, 0, 0)]
+    else:
+        raise ValueError(f"points must be 7 or 27, got {points}")
+    n = nx * ny * nz
+    x, y, z = np.meshgrid(np.arange(nx), np.arange(ny), np.arange(nz),
+                          indexing="ij")
+    x, y, z = (a.transpose(2, 1, 0).reshape(-1) for a in (x, y, z))
+    rng = np.random.default_rng(seed)
+
+    def offset(s):
+        return s[0] + nx * (s[1] + ny * s[2])
+
+    def inside(s):
+        return ((x + s[0] >= 0) & (x + s[0] < nx) & (y + s[1] >= 0)
+                & (y + s[1] < ny) & (z + s[2] >= 0) & (z + s[2] < nz))
+
+    # one conductance per undirected edge: drawn for the positive-offset
+    # direction at its lower row, read back by the negative direction
+    cond = {}
+    for s in sorted(steps, key=offset):
+        if offset(s) > 0:
+            cond[s] = (1.0 + 0.5 * rng.random(n)).astype(dtype)
+    diag = np.zeros(n, dtype)
+    entries = {}                                   # offset -> (n,) values
+    for s in steps:
+        off = offset(s)
+        up = s if off > 0 else tuple(-v for v in s)
+        c = cond[up] if off > 0 else np.roll(cond[up], -off)
+        diag += c                 # in-grid and Dirichlet (leaving) edges
+        entries[off] = np.where(inside(s), -c, 0).astype(dtype)
+    entries[0] = diag
+    offsets = tuple(sorted(entries))
+    dia_vals = np.stack([entries[o] for o in offsets])
+
+    rows = np.arange(n)
+    ell_cols = np.stack([rows + o for o in offsets], axis=1)
+    ell_vals = dia_vals.T.copy()
+    ell_cols = np.where(ell_vals != 0, ell_cols, 0).astype(np.int32)
+    keep = ell_vals != 0
+    rowp = np.concatenate([[0], np.cumsum(keep.sum(axis=1))]).astype(np.int32)
+    return Stencil3D(
+        dia=DIA(diags=jnp.asarray(dia_vals), offsets=offsets, shape=(n, n)),
+        ell=ELL(values=jnp.asarray(ell_vals), cols=jnp.asarray(ell_cols),
+                shape=(n, n)),
+        csr=CSR(matvals=jnp.asarray(ell_vals[keep]),
+                indx=jnp.asarray(ell_cols[keep]), rowp=jnp.asarray(rowp),
+                shape=(n, n)))

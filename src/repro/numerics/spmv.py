@@ -19,7 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import Dense, arbb_for, call, emap, section, shift, unwrap, wrap
+from repro.core import Dense, arbb_for, call, emap, section, unwrap, wrap
 from repro.core import registry
 from repro.core.registry import Cost
 from repro.numerics.sparse import CSR, DIA, ELL, csr_row_ids
@@ -95,14 +95,13 @@ def spmv_ell(ell: ELL, invec: Dense) -> Dense:
 def spmv_dia(dia: DIA, invec: Dense) -> Dense:
     """DIA SpMV: y_i = sum_d diag_d[i] * x[i + off_d] — shifted FMAs only.
 
-    offsets are static, so this is a trace-time (regular-C++-style) loop:
-    gather-free, the TPU-native banded path (DESIGN.md §2)."""
-    x = wrap(invec)
-    n = dia.shape[0]
-    y = Dense.zeros((n,), dia.diags.dtype)
-    for d, off in enumerate(dia.offsets):       # unrolled at trace time
-        y = y + Dense(dia.diags[d]) * shift(x, -off)
-    return y
+    Gather-free, the TPU-native banded path (DESIGN.md §2).  It runs the
+    ``spmv_dia`` kernel op, so the plane decides the body: the Pallas
+    row-tile kernel on TPU, the shifted-FMA reference elsewhere."""
+    from repro.kernels import ops          # lazy: kernels import numerics
+
+    x = unwrap(wrap(invec))
+    return wrap(ops.spmv_dia(dia.diags, dia.offsets, x))
 
 
 def dia_panel(diags, offsets: tuple, xf, row0=0):

@@ -281,9 +281,12 @@ class ContinuousEngine:
                                arrival=arrival, collect_stats=collect_stats)
 
     def _upload_tables(self):
+        # upload snapshots: a host-to-device transfer may read (or, on the
+        # CPU backend, alias) its numpy buffer after the call returns, and
+        # the scheduler mutates these mirrors in place
         self.state = dict(self.state)
-        table = jnp.asarray(self.sched.table)
-        lens = jnp.asarray(self.sched.lens)
+        table = jnp.asarray(self.sched.table.copy())
+        lens = jnp.asarray(self.sched.lens.copy())
         if self._plan is not None:
             # match the committed replicated layout (see __init__) so the
             # upload never perturbs the decode step's jit cache
@@ -308,7 +311,7 @@ class ContinuousEngine:
         active_np = np.zeros((B,), np.int32)
         # device copy of the active mask, refreshed only on lifecycle
         # events (activation / release) — not re-uploaded every step
-        active_dev = [jnp.asarray(active_np)]
+        active_dev = [jnp.asarray(active_np.copy())]
         budget = np.zeros((B,), np.int64)
         gen = np.zeros((B,), np.int64)            # per-slot admission epoch
         live: dict[tuple, Any] = {}               # (slot, gen) -> Request
@@ -331,7 +334,7 @@ class ContinuousEngine:
             refs stay valid.  Token *attribution* stays lagged via ``live``."""
             sched.recycle(slot)
             active_np[slot] = 0
-            active_dev[0] = jnp.asarray(active_np)
+            active_dev[0] = jnp.asarray(active_np.copy())
             if slot in prefilling:
                 prefilling.remove(slot)
             self._upload_tables()
@@ -423,7 +426,7 @@ class ContinuousEngine:
                     budget[slot] = req.max_new - 1
                     if budget[slot] > 0:
                         active_np[slot] = 1
-                        active_dev[0] = jnp.asarray(active_np)
+                        active_dev[0] = jnp.asarray(active_np.copy())
                     else:                 # budget spent: free the slot now
                         release(slot)
                         pending_cur.append(("drain", slot, int(gen[slot])))

@@ -2,12 +2,13 @@
 
     compute term    = FLOPs_per_chip / peak_FLOPs
     memory term     = HBM bytes_per_chip / HBM_bw
-    collective term = collective bytes_per_chip / link_bw
+    collective term = collective bytes_per_chip / ICI_bw
 
-Hardware constants: TPU v5e (the target platform).  ``cost_analysis()`` on a
-partitioned module reports *per-device* flops/bytes, so no division by chip
-count is needed; collective bytes come from the HLO parser (also per-device,
-GSPMD emits the per-shard module).
+Peaks come from one table keyed by ``device_kind`` (:data:`PEAKS`); a kind
+that is not in it raises.  ``cost_analysis()`` on a partitioned module
+reports *per-device* flops/bytes, so no division by chip count is needed;
+collective bytes come from the HLO parser (also per-device, GSPMD emits the
+per-shard module).
 """
 from __future__ import annotations
 
@@ -17,7 +18,8 @@ from typing import Any, Optional
 
 from repro.utils import hlo as hlo_mod
 
-__all__ = ["HW", "TPU_V5E", "RooflineTerms", "analyze", "model_flops"]
+__all__ = ["HW", "PEAKS", "TPU_V5E", "peaks", "RooflineTerms", "analyze",
+           "model_flops"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -25,10 +27,28 @@ class HW:
     name: str
     peak_flops: float          # per chip, bf16
     hbm_bw: float              # bytes/s per chip
-    link_bw: float             # bytes/s per ICI link
+    ici_bw: float              # bytes/s of chip-to-chip interconnect per chip
 
 
-TPU_V5E = HW(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9)
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 16 GB HBM at 819 GB/s, 1,600 Gbit/s (200 GB/s) inter-chip interconnect.
+PEAKS: dict[str, HW] = {
+    "TPU v5 lite": HW(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
+                      ici_bw=1600e9 / 8),
+}
+
+#: The target chip of the dry-run and cost-model predictions.
+TPU_V5E = PEAKS["TPU v5 lite"]
+
+
+def peaks(device_kind: str) -> HW:
+    """The published peaks of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 @dataclasses.dataclass
@@ -44,6 +64,7 @@ class RooflineTerms:
     t_memory: float
     t_collective: float
     model_flops_total: float            # 6·N·D (MoE: active N)
+    peak_flops: float                   # the chip's bf16 peak (PEAKS)
     useful_ratio: float                 # model_flops_per_chip / hlo_flops
     argument_bytes: int = 0
     temp_bytes: int = 0
@@ -72,7 +93,7 @@ class RooflineTerms:
         """Upper bound on model-flops-utilisation: useful flops over peak at
         the step-time lower bound."""
         useful = self.flops_per_chip * self.useful_ratio
-        return useful / (self.step_time * _hw_of(self).peak_flops) \
+        return useful / (self.step_time * self.peak_flops) \
             if self.step_time else 0.0
 
     def to_json(self) -> dict[str, Any]:
@@ -82,13 +103,6 @@ class RooflineTerms:
         d["roofline_fraction"] = self.roofline_fraction
         d["mfu_bound"] = self.mfu_bound
         return d
-
-
-_HW_BY_MESH: dict[int, HW] = {}
-
-
-def _hw_of(t: RooflineTerms) -> HW:
-    return TPU_V5E
 
 
 def model_flops(cfg, n_tokens: int, *, training: bool = True) -> float:
@@ -128,8 +142,9 @@ def analyze(compiled, *, arch: str, shape: str, mesh_name: str,
         coll_breakdown={k: v for k, v in coll.items() if k != "total"},
         t_compute=flops / hw.peak_flops,
         t_memory=hbm_bytes / hw.hbm_bw,
-        t_collective=coll.get("total", 0) / hw.link_bw,
+        t_collective=coll.get("total", 0) / hw.ici_bw,
         model_flops_total=mf,
+        peak_flops=hw.peak_flops,
         useful_ratio=useful,
         argument_bytes=getattr(ma, "argument_size_in_bytes", 0) if ma else 0,
         temp_bytes=getattr(ma, "temp_size_in_bytes", 0) if ma else 0,

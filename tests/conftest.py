@@ -26,13 +26,13 @@ import pytest
 
 @functools.lru_cache(maxsize=1)
 def _interpret_grad_broken() -> bool:
-    """Probe whether differentiating an interpret-mode pallas_call works on
-    this jax.  On jax 0.4.37 the interpret-mode vjp trips an internal
-    AssertionError, which breaks the arch-smoke *train-step* tests whenever
-    ``REPRO_KERNELS=interpret`` routes flash attention through the interpret
-    kernel (pre-existing at the seed; jax-side, not ours).  Probing — rather
-    than pinning a version — means the skip disappears by itself on a jax
-    that can differentiate interpret kernels."""
+    """Probe whether reverse-mode autodiff through a pallas_call works.  On
+    jax 0.9 it does not (linearization fails: pallas_call has no transpose
+    rule, and the flash kernel defines no custom VJP), so the train-step
+    tests cannot run when ``REPRO_KERNELS=interpret`` routes attention
+    through the interpret kernel.  Probing — rather than pinning a version
+    — means the skip disappears by itself once the kernel is
+    differentiable."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -61,7 +61,7 @@ def _arch_differentiates_interpret_kernel(arch: str) -> bool:
 
 
 #: non-parametrised tests that also differentiate the interpret flash
-#: kernel inside a train step (same jax-side breakage as the arch smokes).
+#: kernel inside a train step (same limitation as the arch smokes).
 _GRAD_TRAIN_TESTS = (
     "test_train_step_runs_under_degenerate_mesh",
     "test_loss_decreases_on_learnable_task",
@@ -73,17 +73,17 @@ _GRAD_TRAIN_TESTS = (
 def pytest_collection_modifyitems(config, items):
     """Under ``REPRO_KERNELS=interpret`` (./test.sh's default), skip the
     train-step smoke tests that would differentiate an interpret-mode
-    pallas_call on a jax where that is broken — with the reason stated —
-    so the suite is green in every plane mode."""
+    pallas_call — with the reason stated — so the suite is green in every
+    plane mode."""
     if os.environ.get("REPRO_KERNELS") != "interpret":
         return
     if not _interpret_grad_broken():
         return
     skip = pytest.mark.skip(
-        reason="differentiating interpret-mode pallas_call is broken on "
-               "this jax (probe failed); the same train step passes under "
-               "the default plane and the kernels' forward paths are still "
-               "validated in interpret mode")
+        reason="pallas_call is not reverse-mode differentiable (probe "
+               "failed); the same train step passes under the default "
+               "plane and the kernels' forward paths are still validated "
+               "in interpret mode")
     for item in items:
         if any(name in item.nodeid for name in _GRAD_TRAIN_TESTS):
             item.add_marker(skip)
@@ -133,12 +133,11 @@ def mesh8():
     O3 fixture for scope-aware selection and shard_map numerics tests."""
     import jax
 
-    from repro.core import compat
-
     if jax.device_count() < 8:
         pytest.skip(f"needs 8 devices, have {jax.device_count()} "
                     "(XLA_FLAGS set after jax init?)")
-    return compat.make_mesh((8, 1), ("data", "model"))
+    return jax.make_mesh((8, 1), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
 
 
 @pytest.fixture
@@ -148,9 +147,8 @@ def mesh222():
     2-D (data, model) matmul tiling, and pod-aware CG all exercise on it."""
     import jax
 
-    from repro.core import compat
-
     if jax.device_count() < 8:
         pytest.skip(f"needs 8 devices, have {jax.device_count()} "
                     "(XLA_FLAGS set after jax init?)")
-    return compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 3)

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import ExecLevel, compat, registry, use_level
+from repro.core import ExecLevel, registry, use_level
 from repro.core.registry import Cost
 from repro.kernels import flash_attention as fa_k
 from repro.kernels import ops, ref

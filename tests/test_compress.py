@@ -41,12 +41,11 @@ def test_error_feedback_conserves_information():
 
 def test_compressed_psum_sums_across_axis():
     from jax.sharding import Mesh
-    import jax.experimental.shard_map as shard_map
     devs = np.array(jax.devices()[:1])
     mesh = Mesh(devs, ("pod",))
     x = jnp.asarray(np.linspace(-1, 1, 64), jnp.float32)
 
-    out = shard_map.shard_map(
+    out = jax.shard_map(
         lambda v: compressed_psum(v, "pod"),
         mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
         out_specs=jax.sharding.PartitionSpec())(x)

@@ -10,7 +10,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config, list_configs
 from repro.distributed.partition import (param_specs, zero1_specs,
                                          batch_spec, data_axes)
-from repro.core import compat
 from repro.launch.mesh import make_mesh
 from repro.models.lm import LM
 from repro.utils import hlo
@@ -110,7 +109,7 @@ def test_train_step_runs_under_degenerate_mesh():
     batch = {"tokens": jnp.zeros((2, 8), jnp.int32),
              "labels": jnp.zeros((2, 8), jnp.int32)}
     mesh = make_mesh(data=1, model=1)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         state2, metrics = jax.jit(make_train_step(lm, opt))(state, batch)
     assert np.isfinite(float(metrics["loss"]))
 
@@ -119,7 +118,7 @@ def test_moe_groups_follow_mesh():
     from repro.models.moe import _default_groups
     assert _default_groups(64) == 1          # no mesh
     mesh = make_mesh(data=1, model=1)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         assert _default_groups(64) == 1      # 1-wide data axis
 
 
@@ -171,13 +170,23 @@ class TestRooflineModel:
             arch="a", shape="s", mesh="16x16",
             flops_per_chip=197e12 * 0.010,          # 10 ms of compute
             hbm_bytes_per_chip=819e9 * 0.005,       # 5 ms of HBM
-            coll_bytes_per_chip=50e9 * 0.002,       # 2 ms of ICI
+            coll_bytes_per_chip=200e9 * 0.002,      # 2 ms of ICI
             coll_breakdown={}, t_compute=0.010, t_memory=0.005,
-            t_collective=0.002, model_flops_total=0.0, useful_ratio=0.5)
+            t_collective=0.002, model_flops_total=0.0,
+            peak_flops=TPU_V5E.peak_flops, useful_ratio=0.5)
         assert t.dominant == "compute"
         assert t.step_time == pytest.approx(0.010)
         assert t.roofline_fraction == pytest.approx(1.0)
         assert t.mfu_bound == pytest.approx(0.5)
+
+    def test_peaks_table_is_keyed_by_device_kind(self):
+        from repro.utils.roofline import TPU_V5E, peaks
+        hw = peaks("TPU v5 lite")
+        assert hw is TPU_V5E
+        assert (hw.peak_flops, hw.hbm_bw, hw.ici_bw) == (197e12, 819e9,
+                                                         200e9)
+        with pytest.raises(KeyError, match="no published peaks"):
+            peaks("cpu")
 
     def test_model_flops_moe_uses_active(self):
         from repro.utils.roofline import model_flops

@@ -235,9 +235,10 @@ class TestHierarchicalO4:
 
     def test_axis_roles_declaration_drives_the_plan(self):
         """Exotic axis names become a hierarchy via the scoped role map."""
-        from repro.core import axis_roles, compat
+        from repro.core import axis_roles
 
-        mesh = compat.make_mesh((2, 4), ("replica", "shard"))
+        mesh = jax.make_mesh((2, 4), ("replica", "shard"),
+                             (jax.sharding.AxisType.Auto,) * 2)
         with axis_roles(replica="pod", shard="data"):
             plan = collectives.reduce_plan(mesh)
         assert plan.hierarchical and plan.pod_axes == ("replica",)

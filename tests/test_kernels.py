@@ -61,6 +61,27 @@ def test_spmv_ell_kernel_sweep(nrows, width, dtype):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("x_vmem_bytes", [1 << 24, 0],
+                         ids=["x_in_vmem", "x_in_hbm_windows"])
+def test_spmv_ell_kernel_multi_block_banded(x_vmem_bytes):
+    """Several row blocks, a band wider than one sub-panel plus far
+    columns, and both x placements (whole in VMEM / DMA windows)."""
+    from repro.kernels import spmv as spmv_k
+
+    n, width = 5000, 6
+    rng = np.random.default_rng(7)
+    rows = np.arange(n)[:, None]
+    cols = np.clip(rows + np.array([-2100, -130, -1, 0, 1, 2100]), 0, n - 1)
+    vals = rng.standard_normal((n, width)).astype(np.float32)
+    vals[::7, 3] = 0.0                                  # padding-like holes
+    x = rng.standard_normal(n).astype(np.float32)
+    out = spmv_k.spmv_ell(jnp.asarray(vals), jnp.asarray(cols, jnp.int32),
+                          jnp.asarray(x), block_rows=2048,
+                          x_vmem_bytes=x_vmem_bytes, interpret=True)
+    want = (vals * x[cols]).sum(axis=1)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-4)
+
+
 @pytest.mark.parametrize("n,offsets", [(32, (0,)), (32, (-1, 0, 1)),
                                        (64, (-3, -1, 0, 1, 3)),
                                        (128, (-31, 0, 31))])

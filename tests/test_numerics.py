@@ -56,6 +56,25 @@ class TestMod2as:
             np.asarray(spmv.spmv_dia(dia, C.bind(x)).data), oracle,
             rtol=1e-3, atol=1e-3)
 
+    @pytest.mark.parametrize("points", [7, 27])
+    def test_stencil_3d_matches_dense_construction(self, points):
+        """At 8³ the generator's DIA equals dia_from_dense of its dense
+        operator, and its ELL and CSR hold the same matrix."""
+        op = sparse.stencil_3d(8, points=points, seed=3)
+        a = op.csr.todense()
+        assert op.csr.shape == (512, 512)
+        np.testing.assert_array_equal(a, a.T)
+        want = sparse.dia_from_dense(a)
+        assert op.dia.offsets == want.offsets
+        np.testing.assert_array_equal(np.asarray(op.dia.diags),
+                                      np.asarray(want.diags))
+        vals, cols = np.asarray(op.ell.values), np.asarray(op.ell.cols)
+        dense_ell = np.zeros_like(a)
+        np.add.at(dense_ell, (np.repeat(np.arange(512), vals.shape[1]),
+                              cols.ravel()), vals.ravel())
+        np.testing.assert_array_equal(dense_ell, a)
+        assert np.linalg.eigvalsh(a.astype(np.float64)).min() > 0
+
 
 class TestMod2f:
     @pytest.mark.parametrize("n", [256, 1024, 4096])

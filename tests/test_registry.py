@@ -26,17 +26,22 @@ def _mats(n=32):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.skipif(jax.default_backend() == "tpu",
-                    reason="fallback only happens off-TPU")
+                    reason="a pallas request only fails off-TPU")
 def test_pallas_requested_off_tpu_falls_back_to_xla(monkeypatch):
+    """No fallback: an explicit 'pallas' request off-TPU raises in
+    resolution, selection and dispatch, so no run can pass on another plane
+    while claiming the chip.  With no request, CPU resolves to xla."""
     monkeypatch.delenv("REPRO_KERNELS", raising=False)
     a, b = _mats()
+    assert registry.resolve_backend() == "xla"
+    assert registry.select("matmul", a, b).plane == "xla"
     with registry.use_backend("pallas"):
-        assert registry.resolve_backend() == "xla"
-        v = registry.select("matmul", a, b)
-        assert v.plane == "xla"
-        out = ops.matmul(a, b)              # executes, doesn't crash
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref.matmul_ref(a, b)),
-                               rtol=1e-5, atol=1e-5)
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            registry.resolve_backend()
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            registry.select("matmul", a, b)
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            ops.matmul(a, b)
 
 
 def test_interpret_forced_selects_interpret_variant():
@@ -161,7 +166,7 @@ def test_autotune_keys_carry_scope_and_mesh(tmp_path, monkeypatch):
     local shape (DESIGN.md §8)."""
     if jax.device_count() < 8:
         pytest.skip("needs the 8 forced host devices")
-    from repro.core import ExecLevel, compat, use_level
+    from repro.core import ExecLevel, use_level
 
     path = tmp_path / "at.json"
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(path))
@@ -171,7 +176,8 @@ def test_autotune_keys_carry_scope_and_mesh(tmp_path, monkeypatch):
     assert blocking.ambient_scope_key() == ("chip", "-")
     blocking.resolve_blocks("matmul", dims, "float32", defaults,
                             candidates=({"m": 64},), measure=lambda bl: 1.0)
-    mesh = compat.make_mesh((8, 1), ("data", "model"))
+    mesh = jax.make_mesh((8, 1), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
     with use_level(ExecLevel.O3, mesh):
         assert blocking.ambient_scope_key() == ("mesh", "data8xmodel1")
         blocking.resolve_blocks("matmul", dims, "float32", defaults,

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import ExecLevel, compat, registry, use_level
+from repro.core import ExecLevel, registry, use_level
 from repro.distributed import attention as rattn
 from repro.distributed.collectives import ring_plan
 from repro.kernels import ref
@@ -59,8 +59,9 @@ class TestRingPlan:
         assert plan.spec_entry() == ("pod", "data")
 
     def test_degenerate_mesh_has_no_ring(self):
-        mesh1 = compat.make_mesh((1, 1), ("data", "model"),
-                                 devices=jax.devices()[:1])
+        mesh1 = jax.make_mesh((1, 1), ("data", "model"),
+                              (jax.sharding.AxisType.Auto,) * 2,
+                              devices=jax.devices()[:1])
         assert ring_plan(mesh1).size == 1
 
     def test_zigzag_perm_roundtrip(self):
@@ -108,8 +109,9 @@ class TestRingSelection:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(chip))
 
     def test_one_device_mesh_degrades_to_chip(self):
-        mesh1 = compat.make_mesh((1, 1), ("data", "model"),
-                                 devices=jax.devices()[:1])
+        mesh1 = jax.make_mesh((1, 1), ("data", "model"),
+                              (jax.sharding.AxisType.Auto,) * 2,
+                              devices=jax.devices()[:1])
         q, k, v = _qkv()
         with use_level(ExecLevel.O3, mesh1):
             sel = registry.select("flash_attention", q, k, v, causal=True)
@@ -199,9 +201,9 @@ class TestRingNumerics:
         from conftest import _interpret_grad_broken
         if os.environ.get("REPRO_KERNELS") == "interpret" \
                 and _interpret_grad_broken():
-            pytest.skip("differentiating interpret-mode pallas_call is "
-                        "broken on this jax (probe failed); the ring's "
-                        "grad path is validated under the default plane")
+            pytest.skip("pallas_call is not reverse-mode differentiable "
+                        "(probe failed); the ring's grad path is "
+                        "validated under the default plane")
         q, k, v = _qkv(B=1, H=2, HK=1, L=32, D=8)
 
         def loss(q, variant=None):

@@ -117,6 +117,27 @@ def _fixed_reference(lm, params, reqs):
             for p, m in reqs]
 
 
+#: f32 logits of two numerics paths (chunked/paged vs one-shot forward,
+#: ring-merged vs chip) differ by summation order only
+LOGIT_TOL = 1e-4
+
+
+def _assert_near_argmax(lm, params, reqs, outs):
+    """Teacher-force each request's emitted tokens through the plain
+    forward: every token must carry its row's maximum logit to within
+    ``LOGIT_TOL``.  Greedy token equality across numerics paths breaks on
+    near-ties; this holds each path to the reference logits instead."""
+    for i, ((prompt, max_new), got) in enumerate(zip(reqs, outs)):
+        got = np.asarray(got, np.int32)
+        assert len(got) == max_new, f"request {i}"
+        seq = np.concatenate([prompt, got[:-1]]).astype(np.int32)
+        logits, _ = lm.forward(params, jnp.asarray(seq[None]))
+        rows = np.asarray(logits[0, len(prompt) - 1:], np.float32)
+        chosen = rows[np.arange(max_new), got]
+        gap = rows.max(axis=1) - chosen
+        assert gap.max() <= LOGIT_TOL, (i, gap.max())
+
+
 class TestPagedCacheSpec:
     def test_spec_shapes_and_striping(self):
         spec = make_spec(PCFG, num_slots=4, max_tokens=60)
@@ -284,7 +305,9 @@ class TestChunkedPrefill:
 class TestContinuousEngine:
     def test_matches_fixed_engine_per_request(self):
         """End-to-end continuous generate (tiny): chunked prefill + paged
-        decode reproduce the fixed engine's greedy tokens per request."""
+        decode and the fixed engine both emit, per request, the greedy
+        tokens of the one-shot forward's logits (to ``LOGIT_TOL``) — so
+        they agree wherever the top two logits are not a near-tie."""
         lm, params = _mk()
         reqs = _reqs(4, max_new=5)
         want = _fixed_reference(lm, params, reqs)
@@ -292,8 +315,8 @@ class TestContinuousEngine:
                                chunk_size=4,
                                sampling=SamplingParams(greedy=True))
         got = eng.serve(reqs)
-        for i, (w, g) in enumerate(zip(want, got)):
-            assert g.tolist() == w.tolist(), f"request {i}"
+        _assert_near_argmax(lm, params, reqs, want)
+        _assert_near_argmax(lm, params, reqs, got)
 
     def test_recycling_across_many_admissions(self):
         """3x more requests than slots: every slot recycles repeatedly and
@@ -373,8 +396,8 @@ class TestRingShardedDecode:
                                     sampling=SamplingParams(greedy=True))
         assert ring.spec.ring == 8
         got = ring.serve(reqs)
-        for i, (w, g) in enumerate(zip(want, got)):
-            assert g.tolist() == w.tolist(), f"request {i}"
+        _assert_near_argmax(lm, params, reqs, want)
+        _assert_near_argmax(lm, params, reqs, got)
         assert ring._decode._cache_size() == 1
 
     def test_paged_attention_op_ring_matches_chip_mesh222(self, mesh222):

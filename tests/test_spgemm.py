@@ -45,6 +45,12 @@ def _banded(n=128, bw=7, seed=1):
     return banded_spd(n, bw, seed=seed).astype(np.float32)
 
 
+def _chip_variant():
+    """The chip SpGEMM variant the CPU selects: 'bsr_xla' by default,
+    'bsr_interpret' under an interpret-plane request (./test.sh)."""
+    return "bsr_" + registry.resolve_backend()
+
+
 def _block_occupancy(a, bs):
     n, m = a.shape
     return (a.reshape(n // bs, bs, m // bs, bs) != 0).any(axis=(1, 3))
@@ -102,7 +108,7 @@ class TestChipSpgemm:
     def test_chip_selection_and_pin(self):
         a = S.bsr_from_dense(_blocked(64))
         b = S.bsr_from_dense(_blocked(64, seed=8))
-        assert registry.select("spgemm", a, b).name == "bsr_xla"  # CPU CI
+        assert registry.select("spgemm", a, b).name == _chip_variant()
         assert registry.select("spgemm", a, b,
                                variant="dense").name == "dense"
         with registry.use_backend("interpret"):
@@ -245,7 +251,7 @@ class TestMeshSpgemm:
 
     def test_no_mesh_degrades_to_chip(self):
         _, _, a, b = self._operands()
-        assert registry.select("spgemm", a, b).name == "bsr_xla"
+        assert registry.select("spgemm", a, b).name == _chip_variant()
 
     def test_indivisible_rows_degrade_to_chip(self, mesh8):
         # 72 rows / block 8 = 9 block-rows: not divisible by the 8-wide
@@ -254,7 +260,7 @@ class TestMeshSpgemm:
                                                          frac=0.5)
         a, b = S.bsr_from_dense(A), S.bsr_from_dense(B)
         with use_level(ExecLevel.O3, mesh8):
-            assert registry.select("spgemm", a, b).name == "bsr_xla"
+            assert registry.select("spgemm", a, b).name == _chip_variant()
             C = S.spgemm(a, b)
         assert C.out_sharding is None
         np.testing.assert_allclose(C.todense(), A @ B, rtol=1e-5, atol=1e-4)
