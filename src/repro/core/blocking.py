@@ -343,7 +343,7 @@ def blocked(
             bl.update(pinned)
         blocks = tuple(sorted(bl.items()))
         tracer = obs_trace.TRACER
-        if not tracer.enabled:           # attrs are built lazily on purpose
+        if not tracer.recording():       # attrs are built lazily on purpose
             return padded_call(*args, blocks=blocks, interpret=interpret)
         with tracer.span(f"blocked.pad_call:{op}", cat="blocking", op=op,
                          blocks=",".join(f"{k}={v}" for k, v in blocks)):

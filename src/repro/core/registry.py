@@ -584,42 +584,65 @@ class OperatorRegistry:
         """Select (per the module docstring's rules) and invoke.
 
         Instrumented (DESIGN.md §14): per-(op, variant) selection counts
-        and fall-off counts are always on (two dict bumps); a span per
-        dispatch when the tracer is enabled; whole-call drift timing only
-        under :func:`repro.obs.drift.collect` with concrete arguments —
-        the ``block_until_ready`` it needs is a host sync no default path
+        and fall-off counts are always on (two dict bumps); spans while
+        the tracer's ring is enabled or a profiler session records
+        (:meth:`_dispatch_traced`); whole-call drift timing only under
+        :func:`repro.obs.drift.collect` with concrete arguments — the
+        ``block_until_ready`` it needs is a host sync no default path
         ever pays."""
         if variant is not None:
             v = self.get(op, variant)
             obs_metrics.METRICS.counter(f"dispatch.{op}.{v.name}").inc()
             return _attach_out_sharding(v, None, args, kwargs,
                                         v.impl(*args, **kwargs))
+        if obs_trace.TRACER.recording() or obs_drift.collecting():
+            return self._dispatch_traced(op, args, kwargs)
         v, ctx, rank = self._select(op, args, kwargs)
-        obs_metrics.METRICS.counter(f"dispatch.{op}.{v.name}").inc()
-        if rank > 0:
-            # a higher-ranked candidate was rejected: the degradation
-            # ladder in action (ring→chip, 2-D→1-D, pallas→xla, ...)
-            obs_metrics.METRICS.counter(f"dispatch.falloff.{op}").inc()
+        _count(op, v, rank)
+        return _attach_out_sharding(v, ctx, args, kwargs,
+                                    v.impl(*args, **kwargs))
+
+    def _dispatch_traced(self, op: str, args: tuple, kwargs: dict) -> Any:
+        """:meth:`dispatch` while tracing.  ``dispatch:<op>`` spans the
+        whole dispatch from entry; inside it ``dispatch.select:<op>`` spans
+        :meth:`_select` (context, ranking, cost-model lookup, predicates)
+        and ``dispatch.invoke:<op>`` the variant's call (the jitted call
+        and its launch) with the output-layout attachment.  The variant's
+        args go on ``dispatch:<op>`` in the ring and on
+        ``dispatch.invoke:<op>`` everywhere: a profiler annotation takes
+        its args when it opens, before the variant is known."""
         tracer = obs_trace.TRACER
-        if not (tracer.enabled or obs_drift.collecting()):
-            return _attach_out_sharding(v, ctx, args, kwargs,
-                                        v.impl(*args, **kwargs))
-        scope, mesh = self._scope_mesh(ctx)
-        if rank > 0:
-            tracer.event("dispatch.falloff", cat="dispatch", op=op,
-                         variant=v.name, rank=rank)
-        with tracer.span(f"dispatch:{op}", cat="dispatch", op=op,
-                         variant=v.name, plane=str(v.plane),
-                         scope=v.scope, level=ctx.level.name, mesh=mesh):
-            if obs_drift.collecting() and not _has_tracer(args, kwargs):
-                t0 = time.perf_counter()
-                out = jax.block_until_ready(v.impl(*args, **kwargs))
-                obs_drift.DETECTOR.observe(
-                    op, v.name, time.perf_counter() - t0, args, kwargs,
-                    scope=scope, mesh=mesh)
+        with tracer.span(f"dispatch:{op}", cat="dispatch", op=op) as span:
+            with tracer.span(f"dispatch.select:{op}", cat="dispatch"):
+                v, ctx, rank = self._select(op, args, kwargs)
+            _count(op, v, rank)
+            scope, mesh = self._scope_mesh(ctx)
+            info = {"variant": v.name, "plane": str(v.plane),
+                    "scope": v.scope, "level": ctx.level.name, "mesh": mesh}
+            span.set(**info)
+            if rank > 0:
+                tracer.event("dispatch.falloff", cat="dispatch", op=op,
+                             variant=v.name, rank=rank)
+            with tracer.span(f"dispatch.invoke:{op}", cat="dispatch",
+                             **info):
+                if obs_drift.collecting() and not _has_tracer(args, kwargs):
+                    t0 = time.perf_counter()
+                    out = jax.block_until_ready(v.impl(*args, **kwargs))
+                    obs_drift.DETECTOR.observe(
+                        op, v.name, time.perf_counter() - t0, args, kwargs,
+                        scope=scope, mesh=mesh)
+                else:
+                    out = v.impl(*args, **kwargs)
                 return _attach_out_sharding(v, ctx, args, kwargs, out)
-            return _attach_out_sharding(v, ctx, args, kwargs,
-                                        v.impl(*args, **kwargs))
+
+
+def _count(op: str, v: Variant, rank: int) -> None:
+    """The always-on selection counters of one dispatch."""
+    obs_metrics.METRICS.counter(f"dispatch.{op}.{v.name}").inc()
+    if rank > 0:
+        # a higher-ranked candidate was rejected: the degradation ladder
+        # in action (ring→chip, 2-D→1-D, pallas→xla, ...)
+        obs_metrics.METRICS.counter(f"dispatch.falloff.{op}").inc()
 
 
 #: Process-global registry instance — the single retargeting plane.

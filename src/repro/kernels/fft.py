@@ -75,6 +75,7 @@ def fft_stage(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
+        name="fft_stage",
         interpret=interpret,
     )(data_re.reshape(2, rows, lanes), data_im.reshape(2, rows, lanes),
       tw_re.reshape(rows, lanes), tw_im.reshape(rows, lanes))

@@ -394,6 +394,7 @@ def flash_attention_tiles(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
+        name="flash_attention_tiles",
         interpret=interpret,
     )(jnp.asarray(layout.rowp), jnp.asarray(layout.mid),
       jnp.asarray(layout.prowp), jnp.asarray(layout.cols),
@@ -493,6 +494,7 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
+        name="flash_attention",
         interpret=interpret,
     )(*prefetch, q, k, v)
     return _squeeze_state(out) if return_state else out

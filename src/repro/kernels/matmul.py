@@ -84,5 +84,6 @@ def matmul(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="matmul",
         interpret=interpret,
     )(a, b)

@@ -116,5 +116,6 @@ def spgemm_bsr(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
+        name="spgemm_bsr",
         interpret=interpret,
     )(a_rowp, a_cols, a_vals, b_rowp, b_cols, b_vals, c_rowp, c_cols)

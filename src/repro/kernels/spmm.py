@@ -83,6 +83,7 @@ def spmm_ell(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="spmm_ell",
         interpret=interpret,
     )(values, cols, x)
 
@@ -140,5 +141,6 @@ def spmm_bsr(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
+        name="spmm_bsr",
         interpret=interpret,
     )(rowp, cols, values, x)

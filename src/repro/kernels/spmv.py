@@ -175,6 +175,7 @@ def spmv_ell(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=vmem + (16 << 20),
         ),
+        name="spmv_ell",
         interpret=interpret,
     )(lo, hi, vt, ct, xp)
     return y.reshape(-1)[:nrows]
@@ -231,6 +232,7 @@ def spmv_dia(
         scratch_shapes=[pltpu.VMEM((1, 3 * tile), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="spmv_dia",
         interpret=interpret,
     )(dp, xpad, xpad, xpad)
     return y[0, :n]

@@ -75,38 +75,39 @@ def cg_solve(a: Matrix, b, *, stop: float = 1e-10, max_iters: int = 1000,
     pick by matrix layout *and* scope — under an active O3/O4 mesh the whole
     solve runs sharded, with every dot product a hierarchical reduction plan
     (intra-pod first, pod boundary last)."""
-    b = wrap(b)
-    bv = unwrap(b)
-    selected = _selected_spmv(a, bv, backend)
-    if selected.scope == "mesh":
-        from repro.distributed import numerics as dnum
-        x, r2, k = dnum.cg_mesh(a, bv, stop=stop, max_iters=max_iters,
-                                variant=backend)
-        return CGResult(x=wrap(x), iterations=k, residual_sq=r2)
-    x0 = jnp.zeros_like(bv)
-    r0 = bv
-    p0 = bv
-    r2_0 = jnp.sum(bv * bv)
+    x, r2, k = _cg_jit_core(a, unwrap(wrap(b)), stop, max_iters, backend)
+    return CGResult(x=wrap(x), iterations=k, residual_sq=r2)
 
+
+def _cg_loop(a: Matrix, bv, stop, max_iters: int, backend: Optional[str]):
+    """The chip CG iteration from x0 = 0, r0 = p0 = b; returns (x, r2, k).
+
+    The body's steps carry ``jax.named_scope``s -- ``cg.spmv``, ``cg.dot``,
+    ``cg.update`` -- which reach the optimised HLO's ``op_name`` metadata,
+    so a profiler trace names each fusion by the CG step it computes."""
     def cond(state):
         x, r, p, r2, k = state
         return jnp.logical_and(r2 > stop, k < max_iters)
 
     def body(state):
         x, r, p, r2, k = state
-        ap = unwrap(_spmv(a, p, backend))                  # Ap = A @ p
-        alpha = r2 / jnp.sum(p * ap)
-        r2_old = r2
-        r_new = r - alpha * ap
-        r2_new = jnp.sum(r_new * r_new)
-        beta = r2_new / r2_old
-        x_new = x + alpha * p
-        p_new = r_new + beta * p
+        with jax.named_scope("cg.spmv"):
+            ap = unwrap(_spmv(a, p, backend))              # Ap = A @ p
+        with jax.named_scope("cg.dot"):
+            alpha = r2 / jnp.sum(p * ap)
+        with jax.named_scope("cg.update"):
+            r_new = r - alpha * ap
+        with jax.named_scope("cg.dot"):
+            r2_new = jnp.sum(r_new * r_new)
+        with jax.named_scope("cg.update"):
+            beta = r2_new / r2
+            x_new = x + alpha * p
+            p_new = r_new + beta * p
         return (x_new, r_new, p_new, r2_new, k + 1)
 
-    state = arbb_while(cond, body, (x0, r0, p0, r2_0, jnp.int32(0)))
-    x, r, p, r2, k = state
-    return CGResult(x=wrap(x), iterations=k, residual_sq=r2)
+    init = (jnp.zeros_like(bv), bv, bv, jnp.sum(bv * bv), jnp.int32(0))
+    x, r, p, r2, k = arbb_while(cond, body, init)
+    return x, r2, k
 
 
 def _cg_jit_core(a: Matrix, bv, stop, max_iters: int, backend: Optional[str]):
@@ -117,23 +118,7 @@ def _cg_jit_core(a: Matrix, bv, stop, max_iters: int, backend: Optional[str]):
         from repro.distributed import numerics as dnum
         return dnum.cg_mesh(a, bv, stop=stop, max_iters=max_iters,
                             variant=backend)
-
-    def cond(state):
-        x, r, p, r2, k = state
-        return jnp.logical_and(r2 > stop, k < max_iters)
-
-    def body(state):
-        x, r, p, r2, k = state
-        ap = unwrap(_spmv(a, p, backend))
-        alpha = r2 / jnp.sum(p * ap)
-        r_new = r - alpha * ap
-        r2_new = jnp.sum(r_new * r_new)
-        beta = r2_new / r2
-        return (x + alpha * p, r_new, r_new + beta * p, r2_new, k + 1)
-
-    init = (jnp.zeros_like(bv), bv, bv, jnp.sum(bv * bv), jnp.int32(0))
-    x, r, p, r2, k = arbb_while(cond, body, init)
-    return x, r2, k
+    return _cg_loop(a, bv, stop, max_iters, backend)
 
 
 cg_jit = call(_cg_jit_core, static_argnums=(3, 4))

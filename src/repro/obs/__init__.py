@@ -1,8 +1,9 @@
 """repro.obs — the runtime observability plane (DESIGN.md §14).
 
-Three zero-dependency instruments plus one dispatch introspection API:
+Three instruments plus one dispatch introspection API:
 
-    trace     span/event tracer, Chrome-trace/Perfetto export
+    trace     span/event tracer, Chrome-trace/Perfetto export, and its
+              bridge to the ``jax.profiler`` timeline
     metrics   counters / gauges / log2 histograms, dict snapshot
     drift     live dispatch timings vs the §11 cost model's calibration
     explain   the ranked dispatch table — every candidate with its

@@ -1,5 +1,5 @@
-"""Zero-dependency span/event tracer with Chrome-trace export
-(DESIGN.md §14).
+"""Span/event tracer with Chrome-trace export, bridged to the JAX
+profiler's timeline (DESIGN.md §14).
 
 The registry can retarget an op five ways across four scopes and nobody
 could *see* it happen: which variant won, what the serve loop spent an
@@ -9,10 +9,17 @@ aggregate half, :mod:`repro.obs.drift` the calibration-staleness check.
 
 Design constraints (all load-bearing):
 
-* **off-by-default, negligible when off** — ``TRACER.span(...)`` on a
-  disabled tracer is one attribute read and a shared no-op context
-  manager; nothing allocates, nothing locks.  Tier-1 timings must not
-  move with the tracer compiled in.
+* **off-by-default, negligible when off** — ``TRACER.span(...)`` with
+  the ring disabled and no profiler session recording is one attribute
+  read, one static call and a shared no-op context manager; nothing
+  allocates, nothing locks.  Tier-1 timings must not move with the
+  tracer compiled in.
+* **on the profiler's clock** — while a ``jax.profiler`` session records,
+  every span and event is also a ``jax.profiler.TraceAnnotation`` of the
+  same name (its args become the annotation's stats), so the program's
+  spans land in the ``.xplane.pb`` beside the device's operations.  The
+  session is the switch: no flag, no environment variable.  The ring
+  keeps its own ``perf_counter_ns`` epoch and does not need the profiler.
 * **ring-buffered** — events land in a ``deque(maxlen=capacity)``; a
   long serve run keeps the most recent window instead of growing without
   bound.
@@ -50,9 +57,14 @@ import time
 from collections import deque
 from typing import Any, Iterator, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "TRACER", "clock", "DEFAULT_CAPACITY"]
 
 DEFAULT_CAPACITY = 65536
+
+#: whether a profiler session is recording: one static call, ~50 ns
+_profiling = TraceAnnotation.is_enabled
 
 
 def clock() -> float:
@@ -71,6 +83,9 @@ class _NullSpan:
     def __enter__(self) -> "_NullSpan":
         return self
 
+    def set(self, **args: Any) -> None:
+        pass
+
     def __exit__(self, *exc: Any) -> bool:
         return False
 
@@ -79,39 +94,55 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: records itself into the tracer's ring on exit."""
+    """One live span: an annotation on the profiler's timeline while a
+    session records (``ann``), and a record in the tracer's ring on exit
+    while the ring is enabled (``tracer``); either may be None."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "_ann", "name", "cat", "args", "_t0")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: dict) -> None:
+    def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
+                 args: dict, profiling: bool) -> None:
         self._tracer = tracer
+        self._ann = TraceAnnotation(name, **args) if profiling else None
         self.name = name
         self.cat = cat
         self.args = args
 
+    def set(self, **args: Any) -> None:
+        """Add args known only after the span opened.  They reach the ring;
+        the profiler's annotation took its args when it opened."""
+        self.args.update(args)
+
     def __enter__(self) -> "_Span":
-        self._tracer._stack().append(self)
-        self._t0 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._tracer is not None:
+            self._tracer._stack().append(self)
+            self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
-        dur = time.perf_counter_ns() - self._t0
-        stack = self._tracer._stack()
-        if stack and stack[-1] is self:
-            stack.pop()
-        parent = stack[-1].name if stack else None
-        self._tracer._emit(self.name, "X", self._t0, cat=self.cat,
-                           dur=dur, args=self.args, parent=parent)
+        tracer = self._tracer
+        if tracer is not None:
+            dur = time.perf_counter_ns() - self._t0
+            stack = tracer._stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            parent = stack[-1].name if stack else None
+            tracer._emit(self.name, "X", self._t0, cat=self.cat,
+                         dur=dur, args=self.args, parent=parent)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
 class Tracer:
     """Thread-safe span/event recorder with a bounded ring buffer.
 
-    ``enabled`` is the single hot-path knob: every instrumentation site
-    checks it (directly or via :meth:`span` returning the shared no-op)
-    before doing any work."""
+    ``enabled`` switches the ring; a recording profiler session switches
+    the annotations.  Every instrumentation site checks :meth:`recording`
+    (directly or via :meth:`span` returning the shared no-op) before doing
+    any work."""
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY) -> None:
         self.enabled = False
@@ -177,24 +208,30 @@ class Tracer:
                 self.dropped += 1
             self._events.append(ev)
 
+    def recording(self) -> bool:
+        """Whether a span would be recorded anywhere: in the ring, or on
+        the timeline of a recording profiler session."""
+        return self.enabled or _profiling()
+
     def span(self, name: str, cat: str = "", **args: Any):
-        """A timed span context manager — the no-op singleton when the
-        tracer is disabled, so call sites never branch themselves."""
-        if not self.enabled:
+        """A timed span context manager — the no-op singleton when neither
+        the ring nor a profiler session records, so call sites never
+        branch themselves."""
+        profiling = _profiling()
+        if not (self.enabled or profiling):
             return _NULL_SPAN
-        return _Span(self, name, cat, args)
+        return _Span(self if self.enabled else None, name, cat, args,
+                     profiling)
 
     def event(self, name: str, cat: str = "", **args: Any) -> None:
-        """An instant event (Chrome ``ph: "i"``)."""
-        if not self.enabled:
-            return
-        self._emit(name, "i", time.perf_counter_ns(), cat=cat, args=args)
-
-    def counter(self, name: str, **values: float) -> None:
-        """A Chrome counter sample (``ph: "C"``) — renders as a track."""
-        if not self.enabled:
-            return
-        self._emit(name, "C", time.perf_counter_ns(), args=values)
+        """An instant event (Chrome ``ph: "i"``; an empty annotation on the
+        profiler's timeline)."""
+        if _profiling():
+            with TraceAnnotation(name, **args):
+                pass
+        if self.enabled:
+            self._emit(name, "i", time.perf_counter_ns(), cat=cat,
+                       args=args)
 
     # -- export -------------------------------------------------------------
 

@@ -241,6 +241,85 @@ class TestExplain:
 
 
 # ---------------------------------------------------------------------------
+# the profiler bridge: registry dispatch spans on the profiler's timeline
+# ---------------------------------------------------------------------------
+
+DISPATCH_SPANS = ("dispatch:spmv_dia", "dispatch.select:spmv_dia",
+                  "dispatch.invoke:spmv_dia")
+
+
+def _dia_args():
+    offsets = (-1, 0, 1)
+    diags = jnp.ones((len(offsets), 32), jnp.float32)
+    return diags, offsets, jnp.arange(32, dtype=jnp.float32)
+
+
+class TestProfilerBridge:
+    def test_off_span_is_the_shared_noop(self):
+        assert not trace.TRACER.recording()
+        assert trace.TRACER.span("x", cat="y", a=1) is trace._NULL_SPAN
+
+    def test_untraced_dispatch_takes_the_fast_path(self, monkeypatch):
+        def traced(*_):
+            raise AssertionError("traced dispatch with nothing recording")
+
+        monkeypatch.setattr(registry.REGISTRY, "_dispatch_traced", traced)
+        diags, offsets, x = _dia_args()
+        jax.block_until_ready(
+            registry.dispatch("spmv_dia", diags, offsets, x))
+
+    def test_ring_dispatch_span_encloses_select_then_invoke(self):
+        diags, offsets, x = _dia_args()
+        with trace.TRACER.tracing():
+            registry.dispatch("spmv_dia", diags, offsets, x)
+        evs = {e["name"]: e for e in trace.TRACER.events()}
+        outer, select, invoke = (evs[n] for n in DISPATCH_SPANS)
+        end = outer["ts"] + outer["dur"] + 1e-6
+        for inner in (select, invoke):
+            assert inner["args"]["parent"] == "dispatch:spmv_dia"
+            assert outer["ts"] <= inner["ts"]
+            assert inner["ts"] + inner["dur"] <= end
+        assert select["ts"] + select["dur"] <= invoke["ts"] + 1e-6
+        assert outer["args"]["variant"] == invoke["args"]["variant"]
+        assert {"op", "variant", "plane", "scope", "level",
+                "mesh"} <= set(outer["args"])
+
+    def test_profiler_session_records_the_dispatch_spans(self, tmp_path):
+        """A profiler session alone switches the spans on: they land in
+        the ``.xplane.pb`` under their own names, nested, and the ring
+        stays empty."""
+        from bench import trace as bench_trace
+
+        diags, offsets, x = _dia_args()
+        jax.block_until_ready(
+            registry.dispatch("spmv_dia", diags, offsets, x))   # warm
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation(bench_trace.WINDOW_SPAN):
+                assert trace.TRACER.recording()
+                jax.block_until_ready(
+                    registry.dispatch("spmv_dia", diags, offsets, x))
+                trace.TRACER.event("t.mark")
+        finally:
+            jax.profiler.stop_trace()
+        assert not trace.TRACER.recording()
+        assert len(trace.TRACER) == 0
+        tr = bench_trace.load(str(tmp_path))
+        lo, hi = tr.window
+        found = {}
+        for s, e, name in tr.host:
+            if lo <= s and e <= hi:
+                found.setdefault(name, []).append((s, e))
+        assert "t.mark" in found
+        for name in DISPATCH_SPANS:
+            assert len(found.get(name, [])) == 1, (name, sorted(found))
+        (s, e), = found["dispatch:spmv_dia"]
+        (ss, se), = found["dispatch.select:spmv_dia"]
+        (is_, ie), = found["dispatch.invoke:spmv_dia"]
+        assert s <= ss <= se <= is_ <= ie <= e
+
+
+# ---------------------------------------------------------------------------
 # metrics: instruments, log2 buckets, registry semantics
 # ---------------------------------------------------------------------------
 
