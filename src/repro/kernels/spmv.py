@@ -26,7 +26,8 @@ no locality (uniform random columns) pays n/128 gathers per chunk and slot.
 
 For the *banded* systems of the CG study (paper Table 2) the DIA kernel below
 removes the gather entirely: each diagonal contributes a shifted FMA over a
-row tile, reading an x window padded by one tile on either side.
+row tile, reading a window of x tiles that fetches each tile once and makes
+its own zero halo.
 """
 from __future__ import annotations
 
@@ -36,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.obs import metrics as obs_metrics
 
 __all__ = ["spmv_ell_kernel", "spmv_ell", "spmv_dia_kernel", "spmv_dia"]
 
@@ -181,21 +184,54 @@ def spmv_ell(
     return y.reshape(-1)[:nrows]
 
 
-def spmv_dia_kernel(diags_ref, xprev_ref, xcur_ref, xnext_ref, o_ref, win_ref,
-                    *, offsets: tuple[int, ...], tile: int):
-    """Banded SpMV over one row tile: y = sum_d diags[d] * x[row + off_d].
+def spmv_dia_kernel(diags_ref, x_ref, o_ref, win_ref,
+                    *, offsets: tuple[int, ...], tile: int, tiles: int,
+                    tail: int):
+    """Banded SpMV, one grid step: y = sum_d diags[d] * x[row + off_d].
 
-    The three x blocks are the tiles before, at and after this one; copied
-    side by side (lane-aligned stores) they form the window every shifted
-    read is a *static slice* of — no rotation, no gather, pure VPU FMAs."""
-    win_ref[:, 0:tile] = xprev_ref[...]
-    win_ref[:, tile:2 * tile] = xcur_ref[...]
-    win_ref[:, 2 * tile:3 * tile] = xnext_ref[...]
-    acc = jnp.zeros((1, tile), jnp.float32)
-    for d, off in enumerate(offsets):            # static: unrolled in Mosaic
-        acc += (diags_ref[pl.ds(d, 1), :].astype(jnp.float32)
-                * win_ref[:, pl.ds(tile + off, tile)])
-    o_ref[...] = acc.astype(o_ref.dtype)
+    ``win_ref`` holds three x tiles side by side, the window every shifted
+    read is a *static slice* of — no rotation, no gather, pure VPU FMAs.
+    Each step shifts it left one tile and appends the x tile it was given,
+    so every x tile comes from HBM once.  Step 0 primes the window with a
+    zero halo and x tile 0; step s > 0 computes row tile s - 1 over
+    ``[x_{s-2}, x_{s-1}, x_s]``.  Zeros stand in for the tiles before the
+    first and after the last, and for the lanes of a ragged last tile past
+    n (``tail`` lanes hold x there), whose contents Pallas leaves
+    unspecified."""
+    s = pl.program_id(0)
+
+    @pl.when(s == 0)
+    def _():
+        win_ref[:, tile:2 * tile] = jnp.zeros((1, tile), jnp.float32)
+
+    @pl.when(s > 0)
+    def _():
+        win_ref[:, 0:tile] = win_ref[:, tile:2 * tile]
+        win_ref[:, tile:2 * tile] = win_ref[:, 2 * tile:3 * tile]
+
+    @pl.when(s < tiles - 1)
+    def _():
+        win_ref[:, 2 * tile:3 * tile] = x_ref[...]
+
+    @pl.when(s == tiles - 1)
+    def _():
+        x = x_ref[...]
+        if tail < tile:
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+            x = jnp.where(lane < tail, x, 0.0)
+        win_ref[:, 2 * tile:3 * tile] = x
+
+    @pl.when(s == tiles)
+    def _():
+        win_ref[:, 2 * tile:3 * tile] = jnp.zeros((1, tile), jnp.float32)
+
+    @pl.when(s > 0)
+    def _():
+        acc = jnp.zeros((1, tile), jnp.float32)
+        for d, off in enumerate(offsets):        # static: unrolled in Mosaic
+            acc += (diags_ref[pl.ds(d, 1), :].astype(jnp.float32)
+                    * win_ref[:, pl.ds(tile + off, tile)])
+        o_ref[...] = acc.astype(o_ref.dtype)
 
 
 def spmv_dia(
@@ -209,30 +245,35 @@ def spmv_dia(
 
     The grid walks row tiles of 8192 lanes, raised to cover max|offset|
     and lowered for short vectors: a tile reads its own diagonals' columns
-    plus the x tiles on either side, so one tile must span max|offset|."""
+    plus the x tiles on either side, so one tile must span max|offset|.
+    x goes in unpadded; the grid takes one step more than there are row
+    tiles, and step s fetches x tile min(s, T - 1), which the kernel
+    appends to its window (``spmv_dia_kernel``)."""
     ndiags, n = diags.shape
     max_off = max((abs(o) for o in offsets), default=0)
     tile = _round_up(max(max_off, min(n, 8192)), LANES)
-    npad = _round_up(n, tile)
-    dp = jnp.pad(diags, ((0, 0), (0, npad - n)))
-    xpad = jnp.pad(x.astype(jnp.float32), (tile, npad - n + tile))[None]
+    tiles = -(-n // tile)
+    dp = jnp.pad(diags, ((0, 0), (0, tiles * tile - n)))
+    obs_metrics.METRICS.gauge("kernels.spmv_dia.x_bytes_per_launch").set(
+        4 * tiles * tile)
 
-    def x_block(shift):
-        return pl.BlockSpec((1, tile), lambda i: (0, i + shift))
+    def row_tile(s):
+        return (0, jnp.maximum(s - 1, 0))
 
     y = pl.pallas_call(
-        functools.partial(spmv_dia_kernel, offsets=tuple(offsets), tile=tile),
-        grid=(npad // tile,),
+        functools.partial(spmv_dia_kernel, offsets=tuple(offsets), tile=tile,
+                          tiles=tiles, tail=n - (tiles - 1) * tile),
+        grid=(tiles + 1,),
         in_specs=[
-            pl.BlockSpec((ndiags, tile), lambda i: (0, i)),
-            x_block(0), x_block(1), x_block(2),
+            pl.BlockSpec((ndiags, tile), row_tile),
+            pl.BlockSpec((1, tile), lambda s: (0, jnp.minimum(s, tiles - 1))),
         ],
-        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, npad), diags.dtype),
+        out_specs=pl.BlockSpec((1, tile), row_tile),
+        out_shape=jax.ShapeDtypeStruct((1, tiles * tile), diags.dtype),
         scratch_shapes=[pltpu.VMEM((1, 3 * tile), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("arbitrary",)),
         name="spmv_dia",
         interpret=interpret,
-    )(dp, xpad, xpad, xpad)
+    )(dp, x.astype(jnp.float32)[None])
     return y[0, :n]

@@ -12,6 +12,7 @@ only one process may load the TPU library, and every xdist worker imports
 this file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +83,21 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _called_from(hlo, name):
+    """The text of HLO computation ``name`` and of every computation it
+    calls, transitively."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%([\w.-]+) .*?\{\n(.*?)^\}",
+                            hlo, re.M | re.S))
+    seen, todo = set(), [name]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.-]+)",
+                               comps[c])
+    return "\n".join(comps[c] for c in seen)
+
+
 @pytest.mark.usefixtures("no_persistent_cache")
 class TestSmokeKernelsCompileForV5e:
     @pytest.mark.parametrize("n,dtype", [(8192, jnp.bfloat16),
@@ -105,11 +121,43 @@ class TestSmokeKernelsCompileForV5e:
             _sds(one_chip, (N_STENCIL,), jnp.float32))
 
     def test_spmv_dia(self, one_chip):
+        """x reaches the kernel unpadded, as one (1, n) operand: no pad is
+        written on the way in."""
         from repro.kernels import spmv as spmv_k
 
-        _compile(lambda d, x: spmv_k.spmv_dia(d, OFFSETS_7PT, x),
-                 _sds(one_chip, (len(OFFSETS_7PT), N_STENCIL), jnp.float32),
-                 _sds(one_chip, (N_STENCIL,), jnp.float32))
+        hlo = _compile(lambda d, x: spmv_k.spmv_dia(d, OFFSETS_7PT, x),
+                       _sds(one_chip, (len(OFFSETS_7PT), N_STENCIL),
+                            jnp.float32),
+                       _sds(one_chip, (N_STENCIL,), jnp.float32)).as_text()
+        assert "pad(" not in hlo
+        operands, = re.findall(r"operand_layout_constraints=\{(.*?\})\}",
+                               hlo)
+        assert re.findall(r"\w+\[[\d,]*\]", operands) == [
+            f"f32[{len(OFFSETS_7PT)},{N_STENCIL}]", f"f32[1,{N_STENCIL}]"]
+
+    @pytest.mark.usefixtures("tpu_plane")
+    def test_cg_dia_loop_writes_no_pad(self, one_chip):
+        """``cg_solve`` on a DIA operator under the Pallas plane: its
+        while body (and every computation it calls) holds the kernel and
+        no pad of p."""
+        from repro.core import ExecLevel, unwrap, use_level
+        from repro.numerics.solvers import cg_solve
+        from repro.numerics.sparse import DIA
+
+        def solve(diags, b):
+            a = DIA(diags=diags, offsets=OFFSETS_7PT,
+                    shape=(N_STENCIL, N_STENCIL))
+            return unwrap(cg_solve(a, b, max_iters=10).x)
+
+        with use_level(ExecLevel.O2):
+            hlo = _compile(solve, _sds(one_chip, (len(OFFSETS_7PT),
+                                                  N_STENCIL), jnp.float32),
+                           _sds(one_chip, (N_STENCIL,), jnp.float32)
+                           ).as_text()
+        body = _called_from(hlo, re.search(r"while\(.*\bbody=%([\w.-]+)",
+                                           hlo).group(1))
+        assert "tpu_custom_call" in body
+        assert "pad(" not in body
 
     def test_fft_stage(self, one_chip):
         from repro.kernels import fft as fft_k

@@ -82,9 +82,20 @@ def test_spmv_ell_kernel_multi_block_banded(x_vmem_bytes):
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-4)
 
 
-@pytest.mark.parametrize("n,offsets", [(32, (0,)), (32, (-1, 0, 1)),
-                                       (64, (-3, -1, 0, 1, 3)),
-                                       (128, (-31, 0, 31))])
+DIA_CASES = [
+    (32, (0,)), (32, (-1, 0, 1)), (64, (-3, -1, 0, 1, 3)), (128, (-31, 0, 31)),
+    # several tiles, max|offset| = tile: the halo is a whole neighbour tile
+    (3 * 8192, (-8192, -1, 0, 1, 8192)),
+    # max|offset| < tile, ragged last tile: its lanes past n are masked
+    (5 * 8192 + 640, (-300, 0, 300)),
+    # the offset raises the tile, and the last tile is ragged
+    (40_000, (-10_000, -100, 0, 100, 10_000)),
+    # one-sided offsets: the low halo is never read
+    (20_000, (0, 1, 5000)),
+]
+
+
+@pytest.mark.parametrize("n,offsets", DIA_CASES)
 def test_spmv_dia_kernel_sweep(n, offsets):
     rng = np.random.default_rng(n + len(offsets))
     diags = jnp.asarray(rng.standard_normal((len(offsets), n)), jnp.float32)
@@ -94,6 +105,21 @@ def test_spmv_dia_kernel_sweep(n, offsets):
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.spmv_dia_ref(diags, offsets, x)),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_spmv_dia_fetches_each_x_tile_once():
+    """The launch plan's x traffic, set at trace time: each of the T row
+    tiles of x is fetched once (a window of three reads would be 12·T·tile).
+    The shape is this test's own, so the call traces afresh."""
+    from repro.obs import METRICS
+
+    n, offsets, tile = 3 * 8192 + 256, (-2, 0, 2), 8192
+    gauge = METRICS.gauge("kernels.spmv_dia.x_bytes_per_launch")
+    gauge.set(0)
+    diags = jnp.ones((len(offsets), n), jnp.float32)
+    with ops.backend("interpret"):
+        ops.spmv_dia(diags, offsets, jnp.ones(n, jnp.float32))
+    assert gauge.value == 4 * 4 * tile
 
 
 @pytest.mark.parametrize("logn", [3, 6, 8, 10, 12])
