@@ -23,6 +23,9 @@ schedules**:
                   crosses the pod boundary
     all_gather    gather intra-pod first, then inter-pod — the dual of the
                   sharding order, so row shards reassemble in global order
+    halo          the rows just before and just after a row shard, from
+                  its two neighbours in the same pod-major order: two
+                  ``ppermute`` shifts, no gather
 
 Plans are frozen/hashable, so shard_map executables cache per plan
 (``lru_cache``) exactly as the PR 2 kernels cached per mesh.  On an O3 mesh
@@ -187,6 +190,22 @@ class ReducePlan:
         shard_map."""
         sizes = tuple(self.topo.size(a) for a in self.batch_axes)
         return flat_index(self.batch_axes, sizes)
+
+    def halo(self, x, rows: int):
+        """``(lo, hi)``: the ``rows`` rows of the batch-axis row shards just
+        before and just after this one's ``x`` -- the last rows of shard
+        k - 1 and the first rows of shard k + 1 in the flat pod-major
+        order :meth:`spec_entry` shards by, so the pod seam is one hop like
+        any other.  Two ``ppermute`` shifts over the batch axes; the end
+        shards get zeros where they have no neighbour."""
+        _plan_event("halo", self.batch_axes, rows=rows)
+        w = self.width
+        axis = _entry(self.batch_axes)
+        lo = jax.lax.ppermute(x[x.shape[0] - rows:], axis,
+                              tuple((i, i + 1) for i in range(w - 1)))
+        hi = jax.lax.ppermute(x[:rows], axis,
+                              tuple((i + 1, i) for i in range(w - 1)))
+        return lo, hi
 
 
 def reduce_plan(mesh, topo: Optional[MeshTopology] = None) -> ReducePlan:

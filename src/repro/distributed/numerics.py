@@ -22,10 +22,12 @@ Partitioning per kernel:
 
     solver_spmv  row partition over the batch axes (pod × data).  The matrix
                  shards by rows (ELL values/cols rows; DIA diagonal columns;
-                 CSR row-pointer sections with values/indices replicated),
-                 ``x`` is replicated, and each device runs the *chip*
-                 formulation on its rows — local kernel dispatch inside
-                 ``shard_map``.
+                 CSR row-pointer sections with values/indices replicated)
+                 and each device runs the *chip* formulation on its rows —
+                 local kernel dispatch inside ``shard_map``.  DIA takes x by
+                 rows and exchanges a halo of max|offset| rows with each
+                 neighbouring shard (``ReducePlan.halo``); ELL and CSR take
+                 x whole.
     matmul       ``mesh_psum``: K partition over the batch axes; each device
                  computes a full local MXU product and the partials
                  reduce-scatter intra-pod + all-reduce inter-pod into a
@@ -39,11 +41,13 @@ Partitioning per kernel:
                  (the turn never crosses the slow pod boundary), then column
                  FFTs of length n1.
     cg           the whole O3/O4 solve runs inside one ``shard_map``:
-                 vectors live row-sharded over pod × data, SpMV gathers
-                 ``p`` hierarchically (intra-pod, then inter-pod) once per
-                 iteration, and every dot product is a local partial pushed
-                 through the plan's hierarchical psum — see :func:`cg_mesh`,
-                 consumed by ``repro.numerics.solvers``.
+                 vectors live row-sharded over pod × data; each iteration
+                 the DIA SpMV exchanges its halo of ``p`` with the
+                 neighbouring shards (ELL and CSR gather ``p``
+                 hierarchically, intra-pod then inter-pod), and every dot
+                 product is a local partial pushed through the plan's
+                 hierarchical psum — see :func:`cg_mesh`, consumed by
+                 ``repro.numerics.solvers``.
 """
 from __future__ import annotations
 
@@ -60,6 +64,8 @@ from repro.core.blocking import round_up
 from repro.core.containers import Dense, unwrap, wrap
 from repro.kernels import ref
 from repro.core.topology import topology_of
+from repro.kernels import ops
+from repro.obs import metrics as obs_metrics
 from repro.distributed.collectives import (CannonPlan, ReducePlan, _entry,
                                            ambient_cannon_plan, ambient_plan,
                                            cannon_plan, reduce_plan)
@@ -107,8 +113,8 @@ def _mesh_available(ctx: registry.SelectContext) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# row-partitioned SpMV: matrix shards per layout, x replicated, chip kernel
-# dispatched per shard
+# row-partitioned SpMV: matrix shards per layout, x by rows with a halo (DIA)
+# or whole (ELL, CSR), chip kernel dispatched per shard
 # ---------------------------------------------------------------------------
 #
 # Every mesh entry point below splits into a per-call part (pull the shard
@@ -120,7 +126,7 @@ def _mesh_available(ctx: registry.SelectContext) -> bool:
 # caches the way the bare mesh did in PR 2.
 
 def _spmv_specs(entry) -> dict:
-    """shard_map in_specs per layout's shard arrays (x is prepended as P())."""
+    """shard_map in_specs per layout's shard arrays (x's is prepended)."""
     return {
         "ell": (P(entry, None), P(entry, None)),      # values, cols by rows
         "csr": (P(entry), P(entry), P(), P()),        # rowpi, rowpj; rest whole
@@ -139,13 +145,38 @@ def _spmv_parts(a) -> tuple[str, Any, tuple]:
     raise TypeError(f"no row partitioning for matrix type {type(a)!r}")
 
 
-def _local_spmv(kind: str, static, plan: ReducePlan):
-    """``local(loc, x_full) -> local y rows``, run *inside* shard_map.
+def _max_offset(offsets) -> int:
+    return max((abs(o) for o in offsets), default=0)
 
-    Where the layout allows, the shard is re-wrapped as a container and the
-    matching chip formulation pinned through the registry — the same
-    program text, one shard at a time.
-    """
+
+def _exchange(kind: str, static, plan: ReducePlan):
+    """``exchange(x_loc) -> what this shard's SpMV reads of x``, run *inside*
+    shard_map on the shard's own rows of x.
+
+    DIA reads max|offset| rows past each end of its rows: ``(x_loc, (lo,
+    hi))`` through the plan's halo exchange, two ``ppermute``s of
+    max|offset| rows.  ELL and CSR may read any column: the whole x,
+    all-gathered through the plan."""
+    if kind != "dia":
+        return plan.all_gather
+    rows = _max_offset(static)
+
+    def exchange(x):
+        obs_metrics.METRICS.gauge(
+            "distributed.mesh_dia.exchange_bytes_per_iter").set(
+                2 * rows * x.dtype.itemsize)
+        return x, plan.halo(x, rows)
+    return exchange
+
+
+def _local_spmv(kind: str, static):
+    """``local(loc, x_in) -> local y rows``, run *inside* shard_map, with
+    ``x_in`` what :func:`_exchange` returns (for DIA) or the whole x.
+
+    The shard is handed to the matching chip formulation through the
+    registry -- the same program text, one shard at a time: ELL as a
+    re-wrapped container, DIA as the ``spmv_dia`` op on the shard's rows
+    plus its halo."""
     if kind == "ell":
         def local(loc, xf):
             vals, cols = loc
@@ -163,32 +194,29 @@ def _local_spmv(kind: str, static, plan: ReducePlan):
         return local
 
     offsets = static                                # "dia"
-    maxoff = max((abs(o) for o in offsets), default=0)
 
-    def local(loc, xf):
+    def local(loc, x_in):
         (diags,) = loc                      # (ndiags, n_local)
-        n_local = diags.shape[1]
-        row0 = plan.shard_index() * n_local    # flat pod-major row offset
-        xp = jnp.pad(xf, (maxoff, maxoff))
-        y = jnp.zeros((n_local,), diags.dtype)
-        for d, off in enumerate(offsets):       # static: shifted FMAs
-            seg = jax.lax.dynamic_slice(xp, (row0 + off + maxoff,),
-                                        (n_local,))
-            y = y + diags[d] * seg
-        return y
+        x, halo = x_in
+        return ops.spmv_dia(diags, offsets, x, halo=halo)
     return local
 
 
 @functools.lru_cache(maxsize=None)
 def _spmv_exec(plan: ReducePlan, kind: str, static):
-    local_fn = _local_spmv(kind, static, plan)
+    local_fn = _local_spmv(kind, static)
     entry = plan.spec_entry()
+    if kind == "dia":               # x by rows, the halo exchanged
+        exchange = _exchange(kind, static, plan)
+        x_spec = P(entry)
+    else:                           # x whole on every shard
+        exchange, x_spec = (lambda x: x), P()
 
-    def run(xf, *loc):
-        return local_fn(loc, xf)
+    def run(x, *loc):
+        return local_fn(loc, exchange(x))
 
     return jax.jit(jax.shard_map(run, mesh=plan.mesh,
-                                 in_specs=(P(),) + _spmv_specs(entry)[kind],
+                                 in_specs=(x_spec,) + _spmv_specs(entry)[kind],
                                  out_specs=P(entry), check_vma=False))
 
 
@@ -205,9 +233,13 @@ def _spmv_accepts(layout):
         plan = ambient_plan()
         # 1-D x only: a 2-D multi-RHS x belongs to the spmm plane (the
         # solver_spmv 'spmm' route), whose mesh variant shards the same way
-        return (isinstance(m, layout) and
+        if not (isinstance(m, layout) and
                 getattr(unwrap(v), "ndim", 1) == 1 and plan is not None and
-                m.shape[0] % plan.width == 0)
+                m.shape[0] % plan.width == 0):
+            return False
+        # a DIA shard's halo comes from its two neighbours alone
+        return (layout is not DIA or
+                m.shape[0] // plan.width >= _max_offset(m.offsets))
     return accepts
 
 
@@ -216,7 +248,8 @@ def _spmv_accepts(layout):
 registry.register("solver_spmv", "mesh_dia", mesh_spmv, scope="mesh",
                   cost=4.0, available=_mesh_available,
                   accepts=_spmv_accepts(DIA),
-                  doc="row-sharded banded shifted-FMA over pod x data")
+                  doc="row-sharded DIA over pod x data: halo exchange, "
+                      "chip spmv_dia per shard")
 registry.register("solver_spmv", "mesh_ell", mesh_spmv, scope="mesh",
                   cost=8.0, available=_mesh_available,
                   accepts=_spmv_accepts(ELL),
@@ -678,7 +711,8 @@ registry.register("fft", "mesh_transpose", mesh_fft, scope="mesh", cost=1.0,
 
 @functools.lru_cache(maxsize=None)
 def _cg_exec(plan: ReducePlan, kind: str, static, max_iters: int):
-    local_fn = _local_spmv(kind, static, plan)
+    exchange = _exchange(kind, static, plan)
+    local_fn = _local_spmv(kind, static)
     entry = plan.spec_entry()
 
     def run(stop, b_loc, *a_loc):
@@ -688,14 +722,21 @@ def _cg_exec(plan: ReducePlan, kind: str, static, max_iters: int):
 
         def body(state):
             x, r, p, r2, k = state
-            p_full = plan.all_gather(p)          # intra-pod, then inter-pod
-            ap = local_fn(a_loc, p_full)         # local rows of A@p
-            pap = plan.psum(jnp.sum(p * ap))
-            alpha = r2 / pap
-            r_new = r - alpha * ap
-            r2_new = plan.psum(jnp.sum(r_new * r_new))
-            beta = r2_new / r2
-            return (x + alpha * p, r_new, r_new + beta * p, r2_new, k + 1)
+            with jax.named_scope("cg.exchange"):
+                p_in = exchange(p)               # halo, or the whole of p
+            with jax.named_scope("cg.spmv"):
+                ap = local_fn(a_loc, p_in)       # local rows of A@p
+            with jax.named_scope("cg.dot"):
+                alpha = r2 / plan.psum(jnp.sum(p * ap))
+            with jax.named_scope("cg.update"):
+                r_new = r - alpha * ap
+            with jax.named_scope("cg.dot"):
+                r2_new = plan.psum(jnp.sum(r_new * r_new))
+            with jax.named_scope("cg.update"):
+                beta = r2_new / r2
+                x_new = x + alpha * p
+                p_new = r_new + beta * p
+            return (x_new, r_new, p_new, r2_new, k + 1)
 
         r2_0 = plan.psum(jnp.sum(b_loc * b_loc))
         init = (jnp.zeros_like(b_loc), b_loc, b_loc, r2_0, jnp.int32(0))
@@ -713,13 +754,16 @@ def cg_mesh(a, bv: jax.Array, *, stop, max_iters: int, mesh=None,
     """The paper's §3.4 CG iteration, row-sharded end-to-end.
 
     Vectors (x, r, p) live as row shards over the batch axes (pod × data on
-    O4); each iteration all-gathers ``p`` hierarchically for the local SpMV
-    rows and pushes the two dot products through the plan's hierarchical
-    psum (intra-pod reduce, then one already-reduced scalar across the pod
-    boundary) — the only cross-device traffic.  Loop control (r2, k) is
-    psum-replicated, so every device takes the same branch.  Returns the
-    same (x, r2, k) triple as the chip core, with x row-sharded over the
-    mesh.
+    O4).  Each iteration gives the local SpMV what it reads of ``p`` from
+    other shards -- for DIA the max|offset| rows past each end of the shard
+    (the plan's halo exchange), for ELL and CSR the whole of ``p`` (the
+    plan's hierarchical all-gather) -- and pushes the two dot products
+    through the plan's hierarchical psum (intra-pod reduce, then one
+    already-reduced scalar across the pod boundary): the only cross-device
+    traffic.  The body's steps carry the chip loop's ``named_scope``s plus
+    ``cg.exchange``.  Loop control (r2, k) is psum-replicated, so every
+    device takes the same branch.  Returns the same (x, r2, k) triple as
+    the chip core, with x row-sharded over the mesh.
 
     ``variant`` is the caller's explicit solver_spmv pin, if any: the
     partitioning is determined by the operand layout, so a pin that names a

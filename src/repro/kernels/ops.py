@@ -138,32 +138,35 @@ def spmv_ell(values, cols, x):
 
 
 @functools.partial(jax.jit, static_argnames=("offsets", "interpret"))
-def _spmv_dia_impl(diags, offsets, x, interpret):
-    return spmv_k.spmv_dia(diags, offsets, x, interpret=interpret)
+def _spmv_dia_impl(diags, offsets, x, halo, interpret):
+    return spmv_k.spmv_dia(diags, offsets, x, halo=halo, interpret=interpret)
 
 
 @registry.register("spmv_dia", "pallas", plane="pallas", cost=Cost.PALLAS,
                    doc="banded shifted-FMA kernel, gather-free")
-def _spmv_dia_pallas(diags, offsets, x):
-    return _spmv_dia_impl(diags, offsets, x, interpret=False)
+def _spmv_dia_pallas(diags, offsets, x, halo=None):
+    return _spmv_dia_impl(diags, offsets, x, halo, interpret=False)
 
 
 @registry.register("spmv_dia", "interpret", plane="interpret",
                    cost=Cost.INTERPRET)
-def _spmv_dia_interpret(diags, offsets, x):
-    return _spmv_dia_impl(diags, offsets, x, interpret=True)
+def _spmv_dia_interpret(diags, offsets, x, halo=None):
+    return _spmv_dia_impl(diags, offsets, x, halo, interpret=True)
 
 
 _spmv_dia_ref_jit = jax.jit(ref.spmv_dia_ref, static_argnames=("offsets",))
 
 
 @registry.register("spmv_dia", "xla", plane="xla", cost=Cost.XLA)
-def _spmv_dia_xla(diags, offsets, x):
-    return _spmv_dia_ref_jit(diags, offsets, x)
+def _spmv_dia_xla(diags, offsets, x, halo=None):
+    return _spmv_dia_ref_jit(diags, offsets, x, halo)
 
 
-def spmv_dia(diags, offsets, x):
-    return registry.dispatch("spmv_dia", diags, tuple(offsets), x)
+def spmv_dia(diags, offsets, x, halo=None):
+    """DIA SpMV, ``y[i] = sum_d diags[d, i] * x[i + offsets[d]]``.  ``halo
+    = (lo, hi)`` gives the max|offset| rows of x before and after these
+    rows (a row shard's neighbours'); None reads zeros past the ends."""
+    return registry.dispatch("spmv_dia", diags, tuple(offsets), x, halo=halo)
 
 
 # ---------------------------------------------------------------------------

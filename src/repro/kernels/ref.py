@@ -26,14 +26,17 @@ def spmv_ell_ref(values: jax.Array, cols: jax.Array, x: jax.Array) -> jax.Array:
 
 
 def spmv_dia_ref(diags: jax.Array, offsets: tuple[int, ...],
-                 x: jax.Array) -> jax.Array:
+                 x: jax.Array, halo=None) -> jax.Array:
+    """``y[i] = sum_d diags[d, i] * x[i + offsets[d]]``; past the ends of x
+    the terms are 0, or with ``halo = (lo, hi)`` the max|offset| rows of x
+    before and after it."""
     n = diags.shape[1]
+    m = max((abs(o) for o in offsets), default=0)
+    lo, hi = halo if halo is not None else (jnp.zeros(m, x.dtype),) * 2
+    xp = jnp.concatenate([lo, x, hi])
     y = jnp.zeros(n, diags.dtype)
-    idx = jnp.arange(n)
     for d, off in enumerate(offsets):
-        src = idx + off
-        valid = (src >= 0) & (src < n)
-        y = y + diags[d] * jnp.where(valid, x[jnp.clip(src, 0, n - 1)], 0)
+        y = y + diags[d] * xp[m + off:m + off + n]
     return y
 
 

@@ -27,7 +27,7 @@ no locality (uniform random columns) pays n/128 gathers per chunk and slot.
 For the *banded* systems of the CG study (paper Table 2) the DIA kernel below
 removes the gather entirely: each diagonal contributes a shifted FMA over a
 row tile, reading a window of x tiles that fetches each tile once and makes
-its own zero halo.
+its own zero halo, or takes the rows a row shard's neighbours hold there.
 """
 from __future__ import annotations
 
@@ -184,46 +184,57 @@ def spmv_ell(
     return y.reshape(-1)[:nrows]
 
 
-def spmv_dia_kernel(diags_ref, x_ref, o_ref, win_ref,
-                    *, offsets: tuple[int, ...], tile: int, tiles: int,
-                    tail: int):
+def spmv_dia_kernel(diags_ref, x_ref, *refs, offsets: tuple[int, ...],
+                    tile: int, tiles: int, tail: int):
     """Banded SpMV, one grid step: y = sum_d diags[d] * x[row + off_d].
 
     ``win_ref`` holds three x tiles side by side, the window every shifted
     read is a *static slice* of — no rotation, no gather, pure VPU FMAs.
     Each step shifts it left one tile and appends the x tile it was given,
-    so every x tile comes from HBM once.  Step 0 primes the window with a
-    zero halo and x tile 0; step s > 0 computes row tile s - 1 over
-    ``[x_{s-2}, x_{s-1}, x_s]``.  Zeros stand in for the tiles before the
-    first and after the last, and for the lanes of a ragged last tile past
-    n (``tail`` lanes hold x there), whose contents Pallas leaves
-    unspecified."""
+    so every x tile comes from HBM once.  Step 0 primes the window with the
+    tile before x tile 0 and with x tile 0; step s > 0 computes row tile
+    s - 1 over ``[x_{s-2}, x_{s-1}, x_s]``.  The tiles before the first and
+    after the last, and the lanes of a ragged last tile past n (``tail``
+    lanes hold x there, the rest Pallas leaves unspecified), are zeros, or
+    with ``refs = (halo_ref, o_ref, win_ref)`` the rows of ``halo_ref``:
+    the tile before, the last tile's lanes past n, and the tile after.
+    x and y blocks are (1, tile) or, for a flat vector, (tile,)."""
+    halo_ref = refs[0] if len(refs) == 3 else None
+    o_ref, win_ref = refs[-2:]
     s = pl.program_id(0)
+
+    def fill(row):
+        if halo_ref is None:
+            return jnp.zeros((1, tile), jnp.float32)
+        return halo_ref[pl.ds(row, 1), :]
 
     @pl.when(s == 0)
     def _():
-        win_ref[:, tile:2 * tile] = jnp.zeros((1, tile), jnp.float32)
+        win_ref[:, tile:2 * tile] = fill(0)
 
     @pl.when(s > 0)
     def _():
         win_ref[:, 0:tile] = win_ref[:, tile:2 * tile]
         win_ref[:, tile:2 * tile] = win_ref[:, 2 * tile:3 * tile]
 
+    def x_tile():
+        return x_ref[...].reshape(1, tile)
+
     @pl.when(s < tiles - 1)
     def _():
-        win_ref[:, 2 * tile:3 * tile] = x_ref[...]
+        win_ref[:, 2 * tile:3 * tile] = x_tile()
 
     @pl.when(s == tiles - 1)
     def _():
-        x = x_ref[...]
+        x = x_tile()
         if tail < tile:
             lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-            x = jnp.where(lane < tail, x, 0.0)
+            x = jnp.where(lane < tail, x, fill(1))
         win_ref[:, 2 * tile:3 * tile] = x
 
     @pl.when(s == tiles)
     def _():
-        win_ref[:, 2 * tile:3 * tile] = jnp.zeros((1, tile), jnp.float32)
+        win_ref[:, 2 * tile:3 * tile] = fill(2)
 
     @pl.when(s > 0)
     def _():
@@ -231,7 +242,32 @@ def spmv_dia_kernel(diags_ref, x_ref, o_ref, win_ref,
         for d, off in enumerate(offsets):        # static: unrolled in Mosaic
             acc += (diags_ref[pl.ds(d, 1), :].astype(jnp.float32)
                     * win_ref[:, pl.ds(tile + off, tile)])
-        o_ref[...] = acc.astype(o_ref.dtype)
+        o_ref[...] = acc.reshape(o_ref.shape).astype(o_ref.dtype)
+
+
+def _vmem_bytes(rows: int, lanes: int, dtype) -> int:
+    """VMEM bytes of a (rows, lanes) block: rows fill whole (8, 128) f32
+    tiles (16 rows for 2-byte types)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    return _round_up(rows, 8 * max(1, 4 // itemsize)) * lanes * itemsize
+
+
+def _dia_halo(lo: jax.Array, hi: jax.Array, tile: int, tail: int
+              ) -> jax.Array:
+    """The (3, tile) rows ``spmv_dia_kernel`` takes for its halo: the tile
+    before x tile 0 (``lo`` in its last lanes), the lanes of the last tile
+    past n (``tail`` lanes hold x there; ``hi`` follows), and the tile
+    after it (the rest of ``hi``)."""
+    m = lo.shape[0]
+    return jnp.concatenate([
+        jnp.zeros((tile - m,), jnp.float32), lo.astype(jnp.float32),
+        jnp.zeros((tail,), jnp.float32), hi.astype(jnp.float32),
+        jnp.zeros((2 * tile - tail - m,), jnp.float32)]).reshape(3, tile)
+
+
+#: XLA tiles a 1-D f32 vector by 1024 and a (1, n) array by 128 lanes: the
+#: two share their bytes only where n is a multiple of 1024
+FLAT_TILE = 1024
 
 
 def spmv_dia(
@@ -239,41 +275,83 @@ def spmv_dia(
     offsets: tuple[int, ...],
     x: jax.Array,
     *,
+    halo: tuple[jax.Array, jax.Array] | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """DIA (banded) SpMV.  diags: (ndiags, n) aligned per repro.numerics.sparse.
 
+    ``halo = (lo, hi)``: the max|offset| rows of x just before and just
+    after these n rows (a row shard's neighbours' rows); None reads zeros
+    there, the matrix's own edge.
+
     The grid walks row tiles of 8192 lanes, raised to cover max|offset|
     and lowered for short vectors: a tile reads its own diagonals' columns
     plus the x tiles on either side, so one tile must span max|offset|.
-    x goes in unpadded; the grid takes one step more than there are row
-    tiles, and step s fetches x tile min(s, T - 1), which the kernel
-    appends to its window (``spmv_dia_kernel``)."""
+    x and the diagonals go in unpadded: a ragged last tile is a partial
+    block, its lanes past n masked in x and dropped from y.  The grid takes
+    one step more than there are row tiles, and step s fetches x tile
+    min(s, T - 1), which the kernel appends to its window
+    (``spmv_dia_kernel``).  The VMEM limit is set from the blocks, which
+    grow with the tile.
+
+    x and y go in and out as (1, n), a bitcast of the vector where n is a
+    multiple of :data:`FLAT_TILE`; otherwise as the vector itself in
+    (tile,) blocks, with tiles of whole 1024s, so that XLA copies neither
+    into another layout."""
     ndiags, n = diags.shape
+    flat = n % FLAT_TILE != 0
     max_off = max((abs(o) for o in offsets), default=0)
-    tile = _round_up(max(max_off, min(n, 8192)), LANES)
+    tile = _round_up(max(max_off, min(n, 8192)),
+                     FLAT_TILE if flat else LANES)
     tiles = -(-n // tile)
-    dp = jnp.pad(diags, ((0, 0), (0, tiles * tile - n)))
+    tail = n - (tiles - 1) * tile
     obs_metrics.METRICS.gauge("kernels.spmv_dia.x_bytes_per_launch").set(
         4 * tiles * tile)
 
-    def row_tile(s):
-        return (0, jnp.maximum(s - 1, 0))
+    if flat:
+        vec, vec_shape = (tile,), (n,)
+
+        def row_tile(s):
+            return (jnp.maximum(s - 1, 0),)
+
+        def x_tile(s):
+            return (jnp.minimum(s, tiles - 1),)
+    else:
+        vec, vec_shape = (1, tile), (1, n)
+
+        def row_tile(s):
+            return (0, jnp.maximum(s - 1, 0))
+
+        def x_tile(s):
+            return (0, jnp.minimum(s, tiles - 1))
+
+    operands = [diags, x.astype(jnp.float32).reshape(vec_shape)]
+    in_specs = [pl.BlockSpec((ndiags, tile), lambda s: (0, row_tile(s)[-1])),
+                pl.BlockSpec(vec, x_tile)]
+    # double-buffered diagonal, x and y blocks, and the window
+    vmem = 2 * (_vmem_bytes(ndiags, tile, diags.dtype)
+                + _vmem_bytes(1, tile, jnp.float32)
+                + _vmem_bytes(1, tile, diags.dtype)) \
+        + _vmem_bytes(1, 3 * tile, jnp.float32)
+    if halo is not None:
+        lo, hi = halo
+        assert lo.shape == hi.shape == (max_off,), (lo.shape, hi.shape)
+        operands.append(_dia_halo(lo, hi, tile, tail))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
+        vmem += _vmem_bytes(3, tile, jnp.float32)
 
     y = pl.pallas_call(
         functools.partial(spmv_dia_kernel, offsets=tuple(offsets), tile=tile,
-                          tiles=tiles, tail=n - (tiles - 1) * tile),
+                          tiles=tiles, tail=tail),
         grid=(tiles + 1,),
-        in_specs=[
-            pl.BlockSpec((ndiags, tile), row_tile),
-            pl.BlockSpec((1, tile), lambda s: (0, jnp.minimum(s, tiles - 1))),
-        ],
-        out_specs=pl.BlockSpec((1, tile), row_tile),
-        out_shape=jax.ShapeDtypeStruct((1, tiles * tile), diags.dtype),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(vec, row_tile),
+        out_shape=jax.ShapeDtypeStruct(vec_shape, diags.dtype),
         scratch_shapes=[pltpu.VMEM((1, 3 * tile), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem + (8 << 20)),
         name="spmv_dia",
         interpret=interpret,
-    )(dp, x.astype(jnp.float32)[None])
-    return y[0, :n]
+    )(*operands)
+    return y.reshape(n)

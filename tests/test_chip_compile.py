@@ -135,6 +135,44 @@ class TestSmokeKernelsCompileForV5e:
         assert re.findall(r"\w+\[[\d,]*\]", operands) == [
             f"f32[{len(OFFSETS_7PT)},{N_STENCIL}]", f"f32[1,{N_STENCIL}]"]
 
+    def test_spmv_dia_7pt_256_keeps_operand_shapes(self, one_chip):
+        """The benchmark's 7-point operator at 256^3 rows: a multiple of
+        1024, so x goes in as its (1, n) view, and nothing is padded."""
+        from repro.kernels import spmv as spmv_k
+
+        n, offs = 256 ** 3, (-256 * 256, -256, -1, 0, 1, 256, 256 * 256)
+        hlo = _compile(lambda d, x: spmv_k.spmv_dia(d, offs, x),
+                       _sds(one_chip, (7, n), jnp.float32),
+                       _sds(one_chip, (n,), jnp.float32)).as_text()
+        assert "pad(" not in hlo
+        operands, = re.findall(r"operand_layout_constraints=\{(.*?\})\}",
+                               hlo)
+        assert re.findall(r"\w+\[[\d,]*\]", operands) == [
+            f"f32[7,{n}]", f"f32[1,{n}]"]
+
+    def test_spmv_dia_27pt_shard_with_halo(self, one_chip):
+        """One shard of HPCG's 27-point operator at 360^3 rows a chip, with
+        the max|offset| = 129,961 rows of each neighbour: its blocks fit
+        VMEM, the ragged last tile copies neither the diagonals nor x
+        (46,656,000 is no multiple of 1024, so x and y go as the vector)."""
+        from repro.kernels import spmv as spmv_k
+
+        nx, n = 360, 360 ** 3
+        offs = tuple(sorted({dx + nx * (dy + nx * dz) for dz in (-1, 0, 1)
+                             for dy in (-1, 0, 1) for dx in (-1, 0, 1)}))
+        m = max(abs(o) for o in offs)
+        hlo = _compile(
+            lambda d, x, lo, hi: spmv_k.spmv_dia(d, offs, x, halo=(lo, hi)),
+            _sds(one_chip, (27, n), jnp.float32),
+            _sds(one_chip, (n,), jnp.float32),
+            _sds(one_chip, (m,), jnp.float32),
+            _sds(one_chip, (m,), jnp.float32)).as_text()
+        assert "pad(" not in hlo
+        operands, = re.findall(r"operand_layout_constraints=\{(.*?\})\}",
+                               hlo)
+        assert re.findall(r"\w+\[[\d,]*\]", operands) == [
+            f"f32[27,{n}]", f"f32[{n}]", "f32[3,130048]"]
+
     @pytest.mark.usefixtures("tpu_plane")
     def test_cg_dia_loop_writes_no_pad(self, one_chip):
         """``cg_solve`` on a DIA operator under the Pallas plane: its

@@ -13,10 +13,14 @@ Contracts under test:
     program-text change, degrade to the 1-D forms on O3 and to chip with
     no mesh, and match chip numerics.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 import repro.core as C
 from repro.core import ExecLevel, registry, use_level
@@ -321,3 +325,152 @@ class TestHierarchicalO4:
         ell = sparse.ell_from_csr(sparse.csr_from_dense(a))
         with use_level(ExecLevel.O4, mesh222):
             assert registry.select("solver_spmv", ell, x).name == "ell"
+
+
+# ---------------------------------------------------------------------------
+# the DIA halo exchange: HPCG's 27-point operator row-sharded over 4 devices
+# ---------------------------------------------------------------------------
+
+def _hpcg27(grid):
+    """HPCG's 27-point operator on an nx·ny·nz grid (x fastest): 26 on the
+    diagonal, -1 for every in-grid neighbour.  Returns (DIA, dense f64)."""
+    nx, ny, nz = grid
+    n = nx * ny * nz
+    steps = [(dx, dy, dz) for dz in (-1, 0, 1) for dy in (-1, 0, 1)
+             for dx in (-1, 0, 1)]
+    i = np.arange(n)
+    x, y, z = i % nx, (i // nx) % ny, i // (nx * ny)
+    dense = np.zeros((n, n))
+    by_off = {}
+    for dx, dy, dz in steps:
+        off = dx + nx * (dy + ny * dz)
+        ok = ((0 <= x + dx) & (x + dx < nx) & (0 <= y + dy) & (y + dy < ny)
+              & (0 <= z + dz) & (z + dz < nz))
+        by_off[off] = np.where(off == 0, 26.0, np.where(ok, -1.0, 0.0))
+        dense[i[ok], i[ok] + off] = 26.0 if off == 0 else -1.0
+    offsets = tuple(sorted(by_off))
+    diags = jnp.asarray(np.stack([by_off[o] for o in offsets]), jnp.float32)
+    return sparse.DIA(diags=diags, offsets=offsets, shape=(n, n)), dense
+
+
+#: 4 shards of 256 rows; max|offset| = 8*8 + 8 + 1 = 73
+HPCG_GRID = (8, 8, 16)
+
+
+@pytest.fixture
+def mesh4():
+    return jax.make_mesh((4, 1), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2,
+                         devices=jax.devices()[:4])
+
+
+def _halo_of_each_shard(plan, x, rows):
+    entry = plan.spec_entry()
+
+    return jax.jit(jax.shard_map(lambda xl: plan.halo(xl, rows), mesh=plan.mesh, in_specs=P(entry),
+                                 out_specs=(P(entry), P(entry)),
+                                 check_vma=False))(x)
+
+
+class TestHaloExchange:
+    @pytest.mark.parametrize("mesh_name", ["mesh4", "mesh222"])
+    def test_reduce_plan_halo(self, mesh_name, request):
+        """Shard k gets the last rows of shard k - 1 and the first rows of
+        shard k + 1 in the pod-major order rows shard by; the end shards
+        get zeros.  On (pod, data, model) every model replica alike."""
+        plan = collectives.reduce_plan(request.getfixturevalue(mesh_name))
+        w, per, rows = plan.width, 24, 5
+        x = jnp.arange(1, w * per + 1, dtype=jnp.float32)
+        lo, hi = (np.asarray(v).reshape(w, rows)
+                  for v in _halo_of_each_shard(plan, x, rows))
+        xs = np.asarray(x).reshape(w, per)
+        for k in range(w):
+            want_lo = xs[k - 1, -rows:] if k > 0 else np.zeros(rows)
+            want_hi = xs[k + 1, :rows] if k < w - 1 else np.zeros(rows)
+            np.testing.assert_array_equal(lo[k], want_lo)
+            np.testing.assert_array_equal(hi[k], want_hi)
+
+    def test_mesh_spmv_dia_matches_reference(self, mesh4):
+        dia, dense = _hpcg27(HPCG_GRID)
+        x = np.random.default_rng(5).standard_normal(dia.shape[0]) \
+            .astype(np.float32)
+        want = np.asarray(jnp.dot(jnp.asarray(dense, jnp.float32),
+                                  jnp.asarray(x),
+                                  precision=jax.lax.Precision.HIGHEST))
+        with use_level(ExecLevel.O3, mesh4):
+            assert registry.select("solver_spmv", dia,
+                                   C.bind(x)).name == "mesh_dia"
+            got = registry.dispatch("solver_spmv", dia, C.bind(x)).read()
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+
+    def test_cg_mesh_dia_matches_reference(self, mesh4):
+        """50 iterations of the mesh CG against textbook CG in f32 at
+        HIGHEST precision on the dense operator."""
+        dia, dense = _hpcg27(HPCG_GRID)
+        n = dia.shape[0]
+        b = (1.0 + np.random.default_rng(6).standard_normal(n)) \
+            .astype(np.float32)
+        a = jnp.asarray(dense, jnp.float32)
+        dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+        x, r, p = jnp.zeros(n), jnp.asarray(b), jnp.asarray(b)
+        rr = dot(r, r)
+        for _ in range(50):
+            ap = dot(a, p)
+            alpha = rr / dot(p, ap)
+            x, r = x + alpha * p, r - alpha * ap
+            rr, rr_old = dot(r, r), rr
+            p = r + (rr / rr_old) * p
+        with use_level(ExecLevel.O3, mesh4):
+            got = solvers.cg_solve(dia, C.bind(b), stop=0.0, max_iters=50)
+        assert int(got.iterations) == 50
+        np.testing.assert_allclose(got.x.read(), np.asarray(x),
+                                   rtol=1e-4, atol=1e-5)
+
+    def test_narrow_shard_degrades_to_chip(self, mesh4):
+        """64 rows a shard < max|offset| 73: the halo would need rows of
+        more than one neighbour, so the chip formulation runs."""
+        dia, _ = _hpcg27((8, 8, 4))
+        x = C.bind(np.ones(dia.shape[0], np.float32))
+        with use_level(ExecLevel.O3, mesh4):
+            assert registry.select("solver_spmv", dia, x).name == "dia"
+
+    def test_cg_loop_exchanges_a_halo(self, mesh4):
+        """The mesh CG loop's HLO: ``collective-permute``s for the halo, no
+        all-gather of p and no pad; the exchange gauge reads the bytes one
+        SpMV receives, 2 * max|offset| * 4.  On the XLA plane, whose SpMV
+        the CPU compiles as it is (interpret mode pads Pallas blocks)."""
+        from repro.obs import METRICS
+
+        dia, _ = _hpcg27(HPCG_GRID)
+        gauge = METRICS.gauge("distributed.mesh_dia.exchange_bytes_per_iter")
+        gauge.set(0)
+
+        def solve(d, b):
+            a = sparse.DIA(diags=d, offsets=dia.offsets, shape=dia.shape)
+            return C.unwrap(solvers.cg_solve(a, b, stop=0.0, max_iters=5).x)
+
+        with use_level(ExecLevel.O3, mesh4), registry.use_backend("xla"):
+            hlo = jax.jit(solve).lower(
+                dia.diags, jnp.ones(dia.shape[0], jnp.float32)
+            ).compile().as_text()
+        body = _called_from(hlo, re.search(r"while\(.*\bbody=%([\w.-]+)",
+                                           hlo).group(1))
+        assert "collective-permute" in body
+        assert "all-gather" not in body
+        assert "pad(" not in body
+        assert gauge.value == 2 * 73 * 4
+
+
+def _called_from(hlo, name):
+    """The text of HLO computation ``name`` and of every computation it
+    calls, transitively."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%([\w.-]+) .*?\{\n(.*?)^\}",
+                            hlo, re.M | re.S))
+    seen, todo = set(), [name]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.-]+)",
+                               comps[c])
+    return "\n".join(comps[c] for c in seen)

@@ -107,6 +107,53 @@ def test_spmv_dia_kernel_sweep(n, offsets):
                                rtol=1e-4, atol=1e-4)
 
 
+def _stencil27_offsets(nx, ny):
+    return tuple(sorted({dx + nx * (dy + ny * dz) for dz in (-1, 0, 1)
+                         for dy in (-1, 0, 1) for dx in (-1, 0, 1)}))
+
+
+DIA_HALO_CASES = [
+    # several tiles, max|offset| = tile, n a multiple of 1024: x and y as
+    # (1, n) views
+    (3 * 8192, (-8192, -1, 0, 1, 8192)),
+    # ragged last tile, x and y flat: the high halo starts inside it
+    (5 * 8192 + 640, (-300, 0, 300)),
+    (40_000, (-10_000, -100, 0, 100, 10_000)),
+    # one-sided offsets: the low halo is never read
+    (20_000, (0, 1, 5000)),
+    # one tile, shorter than the tile
+    (1000, (-3, 0, 3)),
+    # the 27-point operator of a 12 x 12 x 10 block: max|offset| 157
+    (12 * 12 * 10, _stencil27_offsets(12, 12)),
+]
+
+
+@pytest.mark.parametrize("n,offsets", DIA_HALO_CASES)
+def test_spmv_dia_kernel_halo(n, offsets):
+    """A row shard with its neighbours' rows: the kernel on (x, halo)
+    equals the whole-vector SpMV's rows of the shard, and ``halo=None``
+    equals zero halos."""
+    m = max(abs(o) for o in offsets)
+    rng = np.random.default_rng(n + m)
+    diags = rng.standard_normal((len(offsets), n)).astype(np.float32)
+    whole = rng.standard_normal(n + 2 * m).astype(np.float32)
+    want = sum(diags[d] * whole[m + off:m + off + n]
+               for d, off in enumerate(offsets))
+    x = jnp.asarray(whole[m:m + n])
+    halo = (jnp.asarray(whole[:m]), jnp.asarray(whole[m + n:]))
+    zeros = (jnp.zeros(m, jnp.float32), jnp.zeros(m, jnp.float32))
+    d = jnp.asarray(diags)
+    with ops.backend("interpret"):
+        got = ops.spmv_dia(d, offsets, x, halo=halo)
+        none = ops.spmv_dia(d, offsets, x)
+        zero = ops.spmv_dia(d, offsets, x, halo=zeros)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(none), np.asarray(zero))
+    np.testing.assert_allclose(
+        np.asarray(ref.spmv_dia_ref(d, offsets, x, halo)), want,
+        rtol=1e-4, atol=1e-4)
+
+
 def test_spmv_dia_fetches_each_x_tile_once():
     """The launch plan's x traffic, set at trace time: each of the T row
     tiles of x is fetched once (a window of three reads would be 12·T·tile).
