@@ -169,14 +169,15 @@ def _exchange(kind: str, static, plan: ReducePlan):
     return exchange
 
 
-def _local_spmv(kind: str, static):
+def _local_spmv(kind: str, static, dot: bool = False):
     """``local(loc, x_in) -> local y rows``, run *inside* shard_map, with
     ``x_in`` what :func:`_exchange` returns (for DIA) or the whole x.
 
     The shard is handed to the matching chip formulation through the
     registry -- the same program text, one shard at a time: ELL as a
     re-wrapped container, DIA as the ``spmv_dia`` op on the shard's rows
-    plus its halo."""
+    plus its halo; with ``dot`` (DIA only) that op also returns x . y over
+    the shard's own rows."""
     if kind == "ell":
         def local(loc, xf):
             vals, cols = loc
@@ -198,7 +199,11 @@ def _local_spmv(kind: str, static):
     def local(loc, x_in):
         (diags,) = loc                      # (ndiags, n_local)
         x, halo = x_in
-        return ops.spmv_dia(diags, offsets, x, halo=halo)
+        if not dot:
+            return ops.spmv_dia(diags, offsets, x, halo=halo)
+        with ops.spmv_dia_dots() as dots:
+            y = ops.spmv_dia(diags, offsets, x, halo=halo)
+        return y, dots[0]
     return local
 
 
@@ -712,7 +717,8 @@ registry.register("fft", "mesh_transpose", mesh_fft, scope="mesh", cost=1.0,
 @functools.lru_cache(maxsize=None)
 def _cg_exec(plan: ReducePlan, kind: str, static, max_iters: int):
     exchange = _exchange(kind, static, plan)
-    local_fn = _local_spmv(kind, static)
+    fused_dot = kind == "dia"           # the kernel returns p.Ap with Ap
+    local_fn = _local_spmv(kind, static, dot=fused_dot)
     entry = plan.spec_entry()
 
     def run(stop, b_loc, *a_loc):
@@ -725,9 +731,14 @@ def _cg_exec(plan: ReducePlan, kind: str, static, max_iters: int):
             with jax.named_scope("cg.exchange"):
                 p_in = exchange(p)               # halo, or the whole of p
             with jax.named_scope("cg.spmv"):
-                ap = local_fn(a_loc, p_in)       # local rows of A@p
+                if fused_dot:                    # local rows of A@p, p.Ap
+                    ap, pap = local_fn(a_loc, p_in)
+                else:
+                    ap = local_fn(a_loc, p_in)   # local rows of A@p
             with jax.named_scope("cg.dot"):
-                alpha = r2 / plan.psum(jnp.sum(p * ap))
+                if not fused_dot:
+                    pap = jnp.sum(p * ap)
+                alpha = r2 / plan.psum(pap)
             with jax.named_scope("cg.update"):
                 r_new = r - alpha * ap
             with jax.named_scope("cg.dot"):
@@ -760,10 +771,12 @@ def cg_mesh(a, bv: jax.Array, *, stop, max_iters: int, mesh=None,
     plan's hierarchical all-gather) -- and pushes the two dot products
     through the plan's hierarchical psum (intra-pod reduce, then one
     already-reduced scalar across the pod boundary): the only cross-device
-    traffic.  The body's steps carry the chip loop's ``named_scope``s plus
-    ``cg.exchange``.  Loop control (r2, k) is psum-replicated, so every
-    device takes the same branch.  Returns the same (x, r2, k) triple as
-    the chip core, with x row-sharded over the mesh.
+    traffic.  For DIA the shard's kernel returns its part of p.Ap with Ap,
+    as in the chip loop.  The body's steps carry the chip loop's
+    ``named_scope``s plus ``cg.exchange``.  Loop control (r2, k) is
+    psum-replicated, so every device takes the same branch.  Returns the
+    same (x, r2, k) triple as the chip core, with x row-sharded over the
+    mesh.
 
     ``variant`` is the caller's explicit solver_spmv pin, if any: the
     partitioning is determined by the operand layout, so a pin that names a

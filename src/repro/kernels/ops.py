@@ -21,6 +21,8 @@ of hardcoded 128s, with explicit per-call overrides still honoured.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 
 import jax
@@ -41,6 +43,7 @@ from repro.sparse.maskcompiler import compile_layout, dense_mask
 from repro.sparse.selector import BLOCKSPARSE_MAX_DENSITY
 
 __all__ = ["backend", "current_backend", "matmul", "spmv_ell", "spmv_dia",
+           "spmv_dia_dots",
            "fft", "flash_attention", "flash_attention_state",
            "paged_attention", "chunk_attention", "page_gather"]
 
@@ -137,36 +140,66 @@ def spmv_ell(values, cols, x):
     return registry.dispatch("spmv_ell", values, cols, x)
 
 
-@functools.partial(jax.jit, static_argnames=("offsets", "interpret"))
-def _spmv_dia_impl(diags, offsets, x, halo, interpret):
-    return spmv_k.spmv_dia(diags, offsets, x, halo=halo, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("offsets", "dot", "interpret"))
+def _spmv_dia_impl(diags, offsets, x, halo, dot, interpret):
+    return spmv_k.spmv_dia(diags, offsets, x, halo=halo, dot=dot,
+                           interpret=interpret)
 
 
 @registry.register("spmv_dia", "pallas", plane="pallas", cost=Cost.PALLAS,
                    doc="banded shifted-FMA kernel, gather-free")
-def _spmv_dia_pallas(diags, offsets, x, halo=None):
-    return _spmv_dia_impl(diags, offsets, x, halo, interpret=False)
+def _spmv_dia_pallas(diags, offsets, x, halo=None, dot=False):
+    return _spmv_dia_impl(diags, offsets, x, halo, dot, interpret=False)
 
 
 @registry.register("spmv_dia", "interpret", plane="interpret",
                    cost=Cost.INTERPRET)
-def _spmv_dia_interpret(diags, offsets, x, halo=None):
-    return _spmv_dia_impl(diags, offsets, x, halo, interpret=True)
+def _spmv_dia_interpret(diags, offsets, x, halo=None, dot=False):
+    return _spmv_dia_impl(diags, offsets, x, halo, dot, interpret=True)
 
 
-_spmv_dia_ref_jit = jax.jit(ref.spmv_dia_ref, static_argnames=("offsets",))
+_spmv_dia_ref_jit = jax.jit(ref.spmv_dia_ref,
+                            static_argnames=("offsets", "dot"))
 
 
 @registry.register("spmv_dia", "xla", plane="xla", cost=Cost.XLA)
-def _spmv_dia_xla(diags, offsets, x, halo=None):
-    return _spmv_dia_ref_jit(diags, offsets, x, halo)
+def _spmv_dia_xla(diags, offsets, x, halo=None, dot=False):
+    return _spmv_dia_ref_jit(diags, offsets, x, halo, dot=dot)
+
+
+#: the list :func:`spmv_dia` appends x . y to, while one is open
+_dia_dots: contextvars.ContextVar = contextvars.ContextVar("spmv_dia_dots",
+                                                           default=None)
+
+
+@contextlib.contextmanager
+def spmv_dia_dots():
+    """Inside it, each :func:`spmv_dia` call also takes ``x . y`` over its
+    rows, which the Pallas kernel does as y streams out (``dot=True``), and
+    appends it to the list this yields; y returns as always.  CG reads
+    p.Ap so, from the call that makes Ap, and keeps reading Ap through
+    ``spmv_dia`` alone, the op's one entry point."""
+    dots = []
+    token = _dia_dots.set(dots)
+    try:
+        yield dots
+    finally:
+        _dia_dots.reset(token)
 
 
 def spmv_dia(diags, offsets, x, halo=None):
     """DIA SpMV, ``y[i] = sum_d diags[d, i] * x[i + offsets[d]]``.  ``halo
     = (lo, hi)`` gives the max|offset| rows of x before and after these
-    rows (a row shard's neighbours'); None reads zeros past the ends."""
-    return registry.dispatch("spmv_dia", diags, tuple(offsets), x, halo=halo)
+    rows (a row shard's neighbours'); None reads zeros past the ends.
+    Inside :func:`spmv_dia_dots` it takes ``x . y`` too."""
+    dots = _dia_dots.get()
+    out = registry.dispatch("spmv_dia", diags, tuple(offsets), x, halo=halo,
+                            dot=dots is not None)
+    if dots is None:
+        return out
+    y, xy = out
+    dots.append(xy)
+    return y
 
 
 # ---------------------------------------------------------------------------

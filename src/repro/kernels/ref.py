@@ -26,10 +26,10 @@ def spmv_ell_ref(values: jax.Array, cols: jax.Array, x: jax.Array) -> jax.Array:
 
 
 def spmv_dia_ref(diags: jax.Array, offsets: tuple[int, ...],
-                 x: jax.Array, halo=None) -> jax.Array:
+                 x: jax.Array, halo=None, dot: bool = False):
     """``y[i] = sum_d diags[d, i] * x[i + offsets[d]]``; past the ends of x
     the terms are 0, or with ``halo = (lo, hi)`` the max|offset| rows of x
-    before and after it."""
+    before and after it.  ``dot=True`` returns ``(y, sum(x * y))``."""
     n = diags.shape[1]
     m = max((abs(o) for o in offsets), default=0)
     lo, hi = halo if halo is not None else (jnp.zeros(m, x.dtype),) * 2
@@ -37,7 +37,7 @@ def spmv_dia_ref(diags: jax.Array, offsets: tuple[int, ...],
     y = jnp.zeros(n, diags.dtype)
     for d, off in enumerate(offsets):
         y = y + diags[d] * xp[m + off:m + off + n]
-    return y
+    return (y, jnp.sum(x * y)) if dot else y
 
 
 def spmm_ell_ref(values: jax.Array, cols: jax.Array, x: jax.Array

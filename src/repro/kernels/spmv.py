@@ -184,8 +184,23 @@ def spmv_ell(
     return y.reshape(-1)[:nrows]
 
 
+def _lane_sums(v: jax.Array) -> jax.Array:
+    """(1, k * 128) -> (1, 128): lane l sums the lanes of v that are l mod
+    128, folded in halves over whole vregs (an odd vreg out goes to
+    ``extra``), so the reduce never leaves the lanes it started in."""
+    extra = jnp.zeros((1, LANES), v.dtype)
+    while v.shape[1] > LANES:
+        if v.shape[1] // LANES % 2:
+            extra = extra + v[:, -LANES:]
+            v = v[:, :-LANES]
+        half = v.shape[1] // 2
+        v = v[:, :half] + v[:, half:]
+    return v + extra
+
+
 def spmv_dia_kernel(diags_ref, x_ref, *refs, offsets: tuple[int, ...],
-                    tile: int, tiles: int, tail: int):
+                    tile: int, tiles: int, tail: int, halo: bool,
+                    dot: bool):
     """Banded SpMV, one grid step: y = sum_d diags[d] * x[row + off_d].
 
     ``win_ref`` holds three x tiles side by side, the window every shifted
@@ -196,11 +211,21 @@ def spmv_dia_kernel(diags_ref, x_ref, *refs, offsets: tuple[int, ...],
     s - 1 over ``[x_{s-2}, x_{s-1}, x_s]``.  The tiles before the first and
     after the last, and the lanes of a ragged last tile past n (``tail``
     lanes hold x there, the rest Pallas leaves unspecified), are zeros, or
-    with ``refs = (halo_ref, o_ref, win_ref)`` the rows of ``halo_ref``:
-    the tile before, the last tile's lanes past n, and the tile after.
-    x and y blocks are (1, tile) or, for a flat vector, (tile,)."""
-    halo_ref = refs[0] if len(refs) == 3 else None
-    o_ref, win_ref = refs[-2:]
+    with ``halo`` the rows of ``halo_ref``: the tile before, the last
+    tile's lanes past n, and the tile after.  x and y blocks are (1, tile)
+    or, for a flat vector, (tile,).
+
+    With ``dot``, ``dot_ref`` is a (1, 1) output resident over the whole
+    grid: ``x . y`` over these rows, x the window's centre tile (the rows'
+    own x, never the halo).  Per-lane partial sums are carried in
+    ``lane_ref``, (1, 128) f32, and the lanes summed at the last step; the
+    lanes of a ragged last tile past n add nothing."""
+    refs = list(refs)
+    halo_ref = refs.pop(0) if halo else None
+    o_ref = refs.pop(0)
+    dot_ref = refs.pop(0) if dot else None
+    win_ref = refs.pop(0)
+    lane_ref = refs.pop(0) if dot else None
     s = pl.program_id(0)
 
     def fill(row):
@@ -236,6 +261,11 @@ def spmv_dia_kernel(diags_ref, x_ref, *refs, offsets: tuple[int, ...],
     def _():
         win_ref[:, 2 * tile:3 * tile] = fill(2)
 
+    if dot:
+        @pl.when(s == 0)
+        def _():
+            lane_ref[...] = jnp.zeros((1, LANES), jnp.float32)
+
     @pl.when(s > 0)
     def _():
         acc = jnp.zeros((1, tile), jnp.float32)
@@ -243,6 +273,23 @@ def spmv_dia_kernel(diags_ref, x_ref, *refs, offsets: tuple[int, ...],
             acc += (diags_ref[pl.ds(d, 1), :].astype(jnp.float32)
                     * win_ref[:, pl.ds(tile + off, tile)])
         o_ref[...] = acc.reshape(o_ref.shape).astype(o_ref.dtype)
+        if not dot:
+            return
+        xy = (win_ref[:, tile:2 * tile]
+              * acc.astype(o_ref.dtype).astype(jnp.float32))
+
+        @pl.when(s < tiles)
+        def _():
+            lane_ref[...] += _lane_sums(xy)
+
+        @pl.when(s == tiles)
+        def _():
+            last = xy
+            if tail < tile:
+                lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+                last = jnp.where(lane < tail, xy, 0.0)
+            lanes = lane_ref[...] + _lane_sums(last)
+            dot_ref[...] = jnp.sum(lanes, axis=1, keepdims=True)
 
 
 def _vmem_bytes(rows: int, lanes: int, dtype) -> int:
@@ -276,13 +323,17 @@ def spmv_dia(
     x: jax.Array,
     *,
     halo: tuple[jax.Array, jax.Array] | None = None,
+    dot: bool = False,
     interpret: bool = False,
-) -> jax.Array:
+) -> jax.Array | tuple[jax.Array, jax.Array]:
     """DIA (banded) SpMV.  diags: (ndiags, n) aligned per repro.numerics.sparse.
 
     ``halo = (lo, hi)``: the max|offset| rows of x just before and just
     after these n rows (a row shard's neighbours' rows); None reads zeros
     there, the matrix's own edge.
+
+    ``dot=True`` returns ``(y, x . y)``, the dot over these n rows taken
+    while x and y are in VMEM (CG's p.Ap); y is the same as without it.
 
     The grid walks row tiles of 8192 lanes, raised to cover max|offset|
     and lowered for short vectors: a tile reads its own diagonals' columns
@@ -340,18 +391,34 @@ def spmv_dia(
         in_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
         vmem += _vmem_bytes(3, tile, jnp.float32)
 
-    y = pl.pallas_call(
+    out_specs = pl.BlockSpec(vec, row_tile)
+    out_shape = jax.ShapeDtypeStruct(vec_shape, diags.dtype)
+    scratch = [pltpu.VMEM((1, 3 * tile), jnp.float32)]
+    if dot:
+        obs_metrics.METRICS.counter("kernels.spmv_dia.dot_fused").inc()
+        out_specs = (out_specs, pl.BlockSpec((1, 1), lambda s: (0, 0)))
+        out_shape = (out_shape, jax.ShapeDtypeStruct((1, 1), jnp.float32))
+        scratch.append(pltpu.VMEM((1, LANES), jnp.float32))
+
+    out = pl.pallas_call(
         functools.partial(spmv_dia_kernel, offsets=tuple(offsets), tile=tile,
-                          tiles=tiles, tail=tail),
+                          tiles=tiles, tail=tail, halo=halo is not None,
+                          dot=dot),
         grid=(tiles + 1,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(vec, row_tile),
-        out_shape=jax.ShapeDtypeStruct(vec_shape, diags.dtype),
-        scratch_shapes=[pltpu.VMEM((1, 3 * tile), jnp.float32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=vmem + (8 << 20)),
         name="spmv_dia",
         interpret=interpret,
     )(*operands)
-    return y.reshape(n)
+    if dot:
+        y, xy = out
+        # Where y's one consumer scales it (CG's alpha * Ap), XLA would move
+        # this reshape past the multiply and run the multiply as a pass of
+        # its own over the (1, n) layout; the barrier keeps y flat for it.
+        return jax.lax.optimization_barrier(y.reshape(n)), xy.reshape(())
+    return out.reshape(n)

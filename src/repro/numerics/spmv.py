@@ -92,15 +92,20 @@ def spmv_ell(ell: ELL, invec: Dense) -> Dense:
     return wrap(jnp.sum(ell.values * gathered, axis=1))
 
 
-def spmv_dia(dia: DIA, invec: Dense) -> Dense:
+def spmv_dia(dia: DIA, invec: Dense, *, dot: bool = False):
     """DIA SpMV: y_i = sum_d diag_d[i] * x[i + off_d] — shifted FMAs only.
 
     Gather-free, the TPU-native banded path (DESIGN.md §2).  It runs the
     ``spmv_dia`` kernel op, so the plane decides the body: the Pallas
-    row-tile kernel on TPU, the shifted-FMA reference elsewhere."""
+    row-tile kernel on TPU, the shifted-FMA reference elsewhere.
+    ``dot=True`` returns ``(y, x . y)``, the dot taken by the same op."""
     from repro.kernels import ops          # lazy: kernels import numerics
 
     x = unwrap(wrap(invec))
+    if dot:
+        with ops.spmv_dia_dots() as dots:
+            y = ops.spmv_dia(dia.diags, dia.offsets, x)
+        return wrap(y), dots[0]
     return wrap(ops.spmv_dia(dia.diags, dia.offsets, x))
 
 

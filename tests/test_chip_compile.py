@@ -12,6 +12,7 @@ only one process may load the TPU library, and every xdist worker imports
 this file.
 """
 import dataclasses
+import math
 import re
 
 import jax
@@ -98,6 +99,36 @@ def _called_from(hlo, name):
     return "\n".join(comps[c] for c in seen)
 
 
+def _reductions_over(hlo, n):
+    """The names of the ``reduce`` instructions in ``hlo`` whose operand
+    holds ``n`` elements."""
+    shapes = dict(re.findall(r"%([\w.-]+) = \w+\[([\d,]*)\]", hlo))
+    return [name for name, operand in
+            re.findall(r"%([\w.-]+) = \S+ reduce\(%([\w.-]+)", hlo)
+            if math.prod(int(d) for d in shapes[operand].split(",") if d)
+            == n]
+
+
+def _cg_dia_body(one_chip):
+    """The optimised HLO of ``cg_solve``'s while body, and of every
+    computation it calls, on the 7-point DIA operator under O2."""
+    from repro.core import ExecLevel, unwrap, use_level
+    from repro.numerics.solvers import cg_solve
+    from repro.numerics.sparse import DIA
+
+    def solve(diags, b):
+        a = DIA(diags=diags, offsets=OFFSETS_7PT,
+                shape=(N_STENCIL, N_STENCIL))
+        return unwrap(cg_solve(a, b, max_iters=10).x)
+
+    with use_level(ExecLevel.O2):
+        hlo = _compile(solve, _sds(one_chip, (len(OFFSETS_7PT), N_STENCIL),
+                                   jnp.float32),
+                       _sds(one_chip, (N_STENCIL,), jnp.float32)).as_text()
+    return _called_from(hlo, re.search(r"while\(.*\bbody=%([\w.-]+)",
+                                       hlo).group(1))
+
+
 @pytest.mark.usefixtures("no_persistent_cache")
 class TestSmokeKernelsCompileForV5e:
     @pytest.mark.parametrize("n,dtype", [(8192, jnp.bfloat16),
@@ -178,24 +209,37 @@ class TestSmokeKernelsCompileForV5e:
         """``cg_solve`` on a DIA operator under the Pallas plane: its
         while body (and every computation it calls) holds the kernel and
         no pad of p."""
-        from repro.core import ExecLevel, unwrap, use_level
-        from repro.numerics.solvers import cg_solve
-        from repro.numerics.sparse import DIA
-
-        def solve(diags, b):
-            a = DIA(diags=diags, offsets=OFFSETS_7PT,
-                    shape=(N_STENCIL, N_STENCIL))
-            return unwrap(cg_solve(a, b, max_iters=10).x)
-
-        with use_level(ExecLevel.O2):
-            hlo = _compile(solve, _sds(one_chip, (len(OFFSETS_7PT),
-                                                  N_STENCIL), jnp.float32),
-                           _sds(one_chip, (N_STENCIL,), jnp.float32)
-                           ).as_text()
-        body = _called_from(hlo, re.search(r"while\(.*\bbody=%([\w.-]+)",
-                                           hlo).group(1))
+        body = _cg_dia_body(one_chip)
         assert "tpu_custom_call" in body
         assert "pad(" not in body
+
+    @pytest.mark.usefixtures("tpu_plane")
+    def test_cg_dia_loop_reduces_only_r_r(self, one_chip):
+        """The kernel returns p.Ap with Ap: outside it the while body
+        reduces one length-n vector, r.r, and no pass reads p and Ap back
+        for p.Ap; nor does a pass scale Ap in the kernel's (1, n) layout
+        on its own (XLA moves the reshape of a lone output past alpha *
+        Ap unless held)."""
+        body = _cg_dia_body(one_chip)
+        (r_r,) = _reductions_over(body, N_STENCIL)
+        assert not re.search(rf"= f32\[1,{N_STENCIL}\]\S* fusion\(", body)
+        kernel, = re.findall(r"= (.*?) custom-call\(", body)
+        assert kernel.startswith(f"(f32[1,{N_STENCIL}]"), kernel
+        assert "f32[1,1]" in kernel, kernel
+
+    @pytest.mark.usefixtures("tpu_plane")
+    def test_spmv_dia_op_takes_no_dot(self, one_chip):
+        """``ops.spmv_dia`` at the SpMV cell's shapes (256^3 rows) builds
+        the launch without the dot: one output, no reduction."""
+        from repro.kernels import ops
+
+        n, offs = 256 ** 3, (-256 * 256, -256, -1, 0, 1, 256, 256 * 256)
+        hlo = _compile(lambda d, x: ops.spmv_dia(d, offs, x),
+                       _sds(one_chip, (7, n), jnp.float32),
+                       _sds(one_chip, (n,), jnp.float32)).as_text()
+        assert "reduce(" not in hlo
+        kernel, = re.findall(r"= (.*?) custom-call\(", hlo)
+        assert kernel.startswith(f"f32[1,{n}]"), kernel
 
     def test_fft_stage(self, one_chip):
         from repro.kernels import fft as fft_k

@@ -357,6 +357,22 @@ def _hpcg27(grid):
 HPCG_GRID = (8, 8, 16)
 
 
+def _textbook_cg(dense, b, iters):
+    """``iters`` iterations of textbook CG from x0 = 0 in f32, every
+    product at HIGHEST precision on the dense operator."""
+    a = jnp.asarray(dense, jnp.float32)
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+    x, r, p = jnp.zeros(len(b)), jnp.asarray(b), jnp.asarray(b)
+    rr = dot(r, r)
+    for _ in range(iters):
+        ap = dot(a, p)
+        alpha = rr / dot(p, ap)
+        x, r = x + alpha * p, r - alpha * ap
+        rr, rr_old = dot(r, r), rr
+        p = r + (rr / rr_old) * p
+    return x
+
+
 @pytest.fixture
 def mesh4():
     return jax.make_mesh((4, 1), ("data", "model"),
@@ -410,20 +426,61 @@ class TestHaloExchange:
         n = dia.shape[0]
         b = (1.0 + np.random.default_rng(6).standard_normal(n)) \
             .astype(np.float32)
-        a = jnp.asarray(dense, jnp.float32)
-        dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
-        x, r, p = jnp.zeros(n), jnp.asarray(b), jnp.asarray(b)
-        rr = dot(r, r)
-        for _ in range(50):
-            ap = dot(a, p)
-            alpha = rr / dot(p, ap)
-            x, r = x + alpha * p, r - alpha * ap
-            rr, rr_old = dot(r, r), rr
-            p = r + (rr / rr_old) * p
+        x = _textbook_cg(dense, b, 50)
         with use_level(ExecLevel.O3, mesh4):
             got = solvers.cg_solve(dia, C.bind(b), stop=0.0, max_iters=50)
         assert int(got.iterations) == 50
         np.testing.assert_allclose(got.x.read(), np.asarray(x),
+                                   rtol=1e-4, atol=1e-5)
+
+    def test_cg_mesh_dia_takes_p_ap_from_the_kernel(self, mesh4):
+        """The mesh CG on DIA with each shard's p.Ap from its own kernel
+        launch, interpreted: 256-row shards end in a ragged 1024-lane tile
+        whose lanes past the shard hold the 73-row high halo.  It matches
+        the chip CG, itself fused, and textbook CG."""
+        from repro.obs import METRICS
+
+        dia, dense = _hpcg27(HPCG_GRID)
+        n = dia.shape[0]
+        b = (1.0 + np.random.default_rng(7).standard_normal(n)) \
+            .astype(np.float32)
+        fused = METRICS.counter("kernels.spmv_dia.dot_fused")
+        jax.clear_caches()              # the counter is set as launches trace
+        before = fused.value
+        with registry.use_backend("interpret"):
+            chip = solvers.cg_solve(dia, C.bind(b), stop=0.0, max_iters=40)
+            assert fused.value == before + 1
+            with use_level(ExecLevel.O3, mesh4):
+                got = solvers.cg_solve(dia, C.bind(b), stop=0.0,
+                                       max_iters=40)
+        assert fused.value == before + 2
+        assert int(got.iterations) == int(chip.iterations) == 40
+        np.testing.assert_allclose(got.x.read(), chip.x.read(),
+                                   rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(got.x.read(),
+                                   np.asarray(_textbook_cg(dense, b, 40)),
+                                   rtol=1e-4, atol=1e-5)
+
+    @pytest.mark.parametrize("layout", ["csr", "ell"])
+    def test_cg_mesh_ell_and_csr_take_p_ap_apart(self, mesh4, layout):
+        """ELL and CSR keep their own p.Ap pass: no DIA launch is built
+        with the dot, and the mesh CG matches textbook CG as before."""
+        from repro.obs import METRICS
+
+        dia, dense = _hpcg27(HPCG_GRID)
+        csr = sparse.csr_from_dense(dense.astype(np.float32))
+        a = csr if layout == "csr" else sparse.ell_from_csr(csr)
+        b = (1.0 + np.random.default_rng(8).standard_normal(dia.shape[0])) \
+            .astype(np.float32)
+        fused = METRICS.counter("kernels.spmv_dia.dot_fused")
+        before = fused.value
+        with registry.use_backend("interpret"), \
+                use_level(ExecLevel.O3, mesh4):
+            got = solvers.cg_solve(a, C.bind(b), stop=0.0, max_iters=30)
+        assert fused.value == before
+        assert int(got.iterations) == 30
+        np.testing.assert_allclose(got.x.read(),
+                                   np.asarray(_textbook_cg(dense, b, 30)),
                                    rtol=1e-4, atol=1e-5)
 
     def test_narrow_shard_degrades_to_chip(self, mesh4):

@@ -169,6 +169,86 @@ def test_spmv_dia_fetches_each_x_tile_once():
     assert gauge.value == 4 * 4 * tile
 
 
+def _dia_dot(diags, offsets, x, halo=None):
+    """The kernel with and without ``dot=True``, interpreted: (y without,
+    y with, the dot)."""
+    from repro.kernels import spmv as spmv_k
+
+    y = spmv_k.spmv_dia(diags, offsets, x, halo=halo, interpret=True)
+    y_dot, xy = spmv_k.spmv_dia(diags, offsets, x, halo=halo, dot=True,
+                                interpret=True)
+    return np.asarray(y), np.asarray(y_dot), float(xy)
+
+
+def _assert_dot_of(x, y, xy):
+    want = np.vdot(np.asarray(x, np.float64), np.asarray(y, np.float64))
+    assert abs(xy - want) <= 1e-5 * abs(want), (xy, want)
+
+
+@pytest.mark.parametrize("n,offsets", DIA_CASES)
+def test_spmv_dia_kernel_dot(n, offsets):
+    """``dot=True``: y bit for bit as without it, and x . y over the rows."""
+    rng = np.random.default_rng(n + len(offsets))
+    diags = jnp.asarray(rng.standard_normal((len(offsets), n)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    y, y_dot, xy = _dia_dot(diags, offsets, x)
+    np.testing.assert_array_equal(y_dot, y)
+    _assert_dot_of(x, y, xy)
+
+
+# every halo case but the first ends in a ragged tile
+@pytest.mark.parametrize("n,offsets", DIA_HALO_CASES[1:])
+def test_spmv_dia_kernel_dot_ragged_tile_with_halo(n, offsets):
+    """A ragged last tile whose lanes past n hold the high halo (and the
+    diagonals' unspecified lanes): they add nothing to x . y, which is over
+    the shard's own rows."""
+    m = max(abs(o) for o in offsets)
+    rng = np.random.default_rng(n + 2 * m)
+    diags = jnp.asarray(rng.standard_normal((len(offsets), n)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    halo = tuple(jnp.asarray(100.0 + 10.0 * rng.standard_normal(m),
+                             jnp.float32) for _ in range(2))
+    y, y_dot, xy = _dia_dot(diags, offsets, x, halo)
+    np.testing.assert_array_equal(y_dot, y)
+    _assert_dot_of(x, y, xy)
+
+
+@pytest.mark.parametrize("plane", ["interpret", "xla"])
+def test_spmv_dia_dots_on_every_plane(plane):
+    """Inside ``spmv_dia_dots`` the op returns y as before and hands x . y
+    to the list; only a launch built with ``dot=True`` counts as fused."""
+    from repro.obs import METRICS
+
+    n, offsets = 3000, (-7, 0, 7)
+    rng = np.random.default_rng(3)
+    diags = jnp.asarray(rng.standard_normal((3, n)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    fused = METRICS.counter("kernels.spmv_dia.dot_fused")
+    jax.clear_caches()                  # the counter is set as launches trace
+    with ops.backend(plane):
+        before = fused.value
+        y = ops.spmv_dia(diags, offsets, x)
+        assert fused.value == before
+        with ops.spmv_dia_dots() as dots:
+            y_dot = ops.spmv_dia(diags, offsets, x)
+    np.testing.assert_array_equal(np.asarray(y_dot), np.asarray(y))
+    (xy,) = dots
+    _assert_dot_of(x, y, float(xy))
+    assert fused.value == before + (plane == "interpret")
+
+
+def test_spmv_dia_kernel_counts_dot_launches():
+    """``kernels.spmv_dia.dot_fused`` counts the launches built with
+    ``dot=True``, at trace time, and no other."""
+    from repro.obs import METRICS
+
+    fused = METRICS.counter("kernels.spmv_dia.dot_fused")
+    before = fused.value
+    diags = jnp.ones((3, 512), jnp.float32)
+    _dia_dot(diags, (-1, 0, 1), jnp.ones(512, jnp.float32))
+    assert fused.value == before + 1
+
+
 @pytest.mark.parametrize("logn", [3, 6, 8, 10, 12])
 def test_fft_kernel_sweep(logn):
     n = 1 << logn
