@@ -40,10 +40,11 @@ Partitioning per kernel:
                  ``all_to_all`` corner turn **within the data subgrid only**
                  (the turn never crosses the slow pod boundary), then column
                  FFTs of length n1.
-    cg           the whole O3/O4 solve runs inside one ``shard_map``:
-                 vectors live row-sharded over pod × data; each iteration
-                 the DIA SpMV exchanges its halo of ``p`` with the
-                 neighbouring shards (ELL and CSR gather ``p``
+    cg           the whole O3/O4 solve runs inside one ``shard_map``, the
+                 solver's own CG loop with this module's exchange, shard
+                 SpMV and psum: vectors live row-sharded over pod × data;
+                 each iteration the DIA SpMV exchanges its halo of ``p``
+                 with the neighbouring shards (ELL and CSR gather ``p``
                  hierarchically, intra-pod then inter-pod), and every dot
                  product is a local partial pushed through the plan's
                  hierarchical psum — see :func:`cg_mesh`, consumed by
@@ -176,8 +177,22 @@ def _local_spmv(kind: str, static, dot: bool = False):
     The shard is handed to the matching chip formulation through the
     registry -- the same program text, one shard at a time: ELL as a
     re-wrapped container, DIA as the ``spmv_dia`` op on the shard's rows
-    plus its halo; with ``dot`` (DIA only) that op also returns x . y over
-    the shard's own rows."""
+    plus its halo.  With ``dot`` it returns ``(y, x . y)`` over the shard's
+    own rows where the formulation takes the dot with y (DIA, in the same
+    op) and ``(y, None)`` otherwise."""
+    if kind == "dia":
+        offsets = static
+
+        def local(loc, x_in):
+            (diags,) = loc                  # (ndiags, n_local)
+            x, halo = x_in
+            if not dot:
+                return ops.spmv_dia(diags, offsets, x, halo=halo)
+            with ops.spmv_dia_dots() as dots:
+                y = ops.spmv_dia(diags, offsets, x, halo=halo)
+            return y, dots[0]
+        return local
+
     if kind == "ell":
         def local(loc, xf):
             vals, cols = loc
@@ -185,26 +200,12 @@ def _local_spmv(kind: str, static, dot: bool = False):
                         shape=(vals.shape[0], xf.shape[0]))
             return unwrap(registry.dispatch("solver_spmv", shard, wrap(xf),
                                             variant="ell"))
-        return local
-
-    if kind == "csr":
+    else:                                           # "csr"
         def local(loc, xf):
             rowpi, rowpj, matvals, indx = loc
             # the paper's map(local::reduce) over this device's row sections
             return jax.vmap(csr_row_reduce(matvals, indx, xf))(rowpi, rowpj)
-        return local
-
-    offsets = static                                # "dia"
-
-    def local(loc, x_in):
-        (diags,) = loc                      # (ndiags, n_local)
-        x, halo = x_in
-        if not dot:
-            return ops.spmv_dia(diags, offsets, x, halo=halo)
-        with ops.spmv_dia_dots() as dots:
-            y = ops.spmv_dia(diags, offsets, x, halo=halo)
-        return y, dots[0]
-    return local
+    return (lambda loc, xf: (local(loc, xf), None)) if dot else local
 
 
 @functools.lru_cache(maxsize=None)
@@ -715,44 +716,15 @@ registry.register("fft", "mesh_transpose", mesh_fft, scope="mesh", cost=1.0,
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _cg_exec(plan: ReducePlan, kind: str, static, max_iters: int):
+def _cg_exec(plan: ReducePlan, kind: str, static, max_iters: int, loop):
     exchange = _exchange(kind, static, plan)
-    fused_dot = kind == "dia"           # the kernel returns p.Ap with Ap
-    local_fn = _local_spmv(kind, static, dot=fused_dot)
+    local_fn = _local_spmv(kind, static, dot=True)
     entry = plan.spec_entry()
 
     def run(stop, b_loc, *a_loc):
-        def cond(state):
-            x, r, p, r2, k = state
-            return jnp.logical_and(r2 > stop, k < max_iters)
-
-        def body(state):
-            x, r, p, r2, k = state
-            with jax.named_scope("cg.exchange"):
-                p_in = exchange(p)               # halo, or the whole of p
-            with jax.named_scope("cg.spmv"):
-                if fused_dot:                    # local rows of A@p, p.Ap
-                    ap, pap = local_fn(a_loc, p_in)
-                else:
-                    ap = local_fn(a_loc, p_in)   # local rows of A@p
-            with jax.named_scope("cg.dot"):
-                if not fused_dot:
-                    pap = jnp.sum(p * ap)
-                alpha = r2 / plan.psum(pap)
-            with jax.named_scope("cg.update"):
-                r_new = r - alpha * ap
-            with jax.named_scope("cg.dot"):
-                r2_new = plan.psum(jnp.sum(r_new * r_new))
-            with jax.named_scope("cg.update"):
-                beta = r2_new / r2
-                x_new = x + alpha * p
-                p_new = r_new + beta * p
-            return (x_new, r_new, p_new, r2_new, k + 1)
-
-        r2_0 = plan.psum(jnp.sum(b_loc * b_loc))
-        init = (jnp.zeros_like(b_loc), b_loc, b_loc, r2_0, jnp.int32(0))
-        x, r, p, r2, k = jax.lax.while_loop(cond, body, init)
-        return x, r2, k
+        return loop(b_loc, stop, max_iters,
+                    spmv=lambda p_in: local_fn(a_loc, p_in),
+                    reduce=plan.psum, exchange=exchange)
 
     return jax.jit(jax.shard_map(
         run, mesh=plan.mesh,
@@ -760,9 +732,11 @@ def _cg_exec(plan: ReducePlan, kind: str, static, max_iters: int):
         out_specs=(P(entry), P(), P()), check_vma=False))
 
 
-def cg_mesh(a, bv: jax.Array, *, stop, max_iters: int, mesh=None,
+def cg_mesh(a, bv: jax.Array, *, stop, max_iters: int, loop, mesh=None,
             variant: Any = None) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """The paper's §3.4 CG iteration, row-sharded end-to-end.
+    """The paper's §3.4 CG iteration, row-sharded end-to-end: ``loop``,
+    the solver's own CG loop (``solvers._cg_loop``), run inside one
+    shard_map with this scope's exchange, shard SpMV and psum.
 
     Vectors (x, r, p) live as row shards over the batch axes (pod × data on
     O4).  Each iteration gives the local SpMV what it reads of ``p`` from
@@ -772,11 +746,9 @@ def cg_mesh(a, bv: jax.Array, *, stop, max_iters: int, mesh=None,
     through the plan's hierarchical psum (intra-pod reduce, then one
     already-reduced scalar across the pod boundary): the only cross-device
     traffic.  For DIA the shard's kernel returns its part of p.Ap with Ap,
-    as in the chip loop.  The body's steps carry the chip loop's
-    ``named_scope``s plus ``cg.exchange``.  Loop control (r2, k) is
-    psum-replicated, so every device takes the same branch.  Returns the
-    same (x, r2, k) triple as the chip core, with x row-sharded over the
-    mesh.
+    as on one chip.  Loop control (r2, k) is psum-replicated, so every
+    device takes the same branch.  Returns the same (x, r2, k) triple as
+    the chip core, with x row-sharded over the mesh.
 
     ``variant`` is the caller's explicit solver_spmv pin, if any: the
     partitioning is determined by the operand layout, so a pin that names a
@@ -792,4 +764,5 @@ def cg_mesh(a, bv: jax.Array, *, stop, max_iters: int, mesh=None,
             f"{type(a).__name__} operand row-partitions as {expected!r}")
     kind, static, arrays = _spmv_parts(a)
     stop = jnp.asarray(stop, bv.dtype)
-    return _cg_exec(plan, kind, static, int(max_iters))(stop, bv, *arrays)
+    return _cg_exec(plan, kind, static, int(max_iters), loop)(stop, bv,
+                                                              *arrays)

@@ -18,7 +18,7 @@ is genuinely needed.
 
 The solve is also **scope-aware** (DESIGN.md §7-§8): under ``use_level(O3)``
 with an ambient mesh the registry selects a mesh-scoped ``solver_spmv``
-variant, and the whole iteration reruns as
+variant, and the same iteration runs inside the shard_map of
 :func:`repro.distributed.numerics.cg_mesh` — vectors row-sharded over the
 batch axes, SpMV local per shard, both dot products pushed through the
 mesh's hierarchical reduction plan (on an O4 ``(pod, data, model)`` mesh:
@@ -55,11 +55,6 @@ class CGResult:
     residual_sq: jax.Array      # f32 scalar, on device
 
 
-def _spmv(a: Matrix, p, backend: Optional[str], **kwargs):
-    return registry.dispatch("solver_spmv", a, wrap(p), variant=backend,
-                             **kwargs)
-
-
 def _selected_spmv(a: Matrix, bv, backend: Optional[str]) -> registry.Variant:
     """The solver_spmv variant the registry would run for this solve —
     the scope decision (chip loop vs mesh shard_map) hangs off its scope."""
@@ -80,44 +75,47 @@ def cg_solve(a: Matrix, b, *, stop: float = 1e-10, max_iters: int = 1000,
     return CGResult(x=wrap(x), iterations=k, residual_sq=r2)
 
 
-def _cg_loop(a: Matrix, bv, stop, max_iters: int, backend: Optional[str],
-             fused_dot: bool):
-    """The chip CG iteration from x0 = 0, r0 = p0 = b; returns (x, r2, k).
+def _cg_loop(bv, stop, max_iters: int, *, spmv, reduce=lambda s: s,
+             exchange=lambda p: p):
+    """The CG iteration from x0 = 0, r0 = p0 = b; returns (x, r2, k).
 
-    The body's steps carry ``jax.named_scope``s -- ``cg.spmv``, ``cg.dot``,
-    ``cg.update`` -- which reach the optimised HLO's ``op_name`` metadata,
-    so a profiler trace names each fusion by the CG step it computes.
-    With ``fused_dot`` (the ``dia`` formulation) the SpMV returns p.Ap
-    with Ap, taken by the kernel while both are on chip, so that dot sits
-    inside ``cg.spmv`` and no pass reads p and Ap back; every other
-    formulation takes it in ``cg.dot``."""
+    The one loop of both scopes; each hands in what differs.
+    ``exchange(p)`` gives what the SpMV reads of p (p on one chip; the
+    halo or the whole of p on the mesh); ``spmv(p_in)`` returns ``(Ap,
+    p.Ap)`` where the formulation takes the dot (DIA's kernel, while both
+    are on chip) and ``(Ap, None)`` otherwise; ``reduce`` sums a partial
+    dot over the shards (the plan's psum on the mesh).
+
+    The body's steps carry ``jax.named_scope``s -- ``cg.exchange``,
+    ``cg.spmv``, ``cg.dot``, ``cg.update`` -- which reach the optimised
+    HLO's ``op_name`` metadata, so a profiler trace names each fusion by
+    the CG step it computes."""
     def cond(state):
         x, r, p, r2, k = state
         return jnp.logical_and(r2 > stop, k < max_iters)
 
     def body(state):
         x, r, p, r2, k = state
+        with jax.named_scope("cg.exchange"):
+            p_in = exchange(p)
         with jax.named_scope("cg.spmv"):
-            if fused_dot:                                   # Ap, p.Ap
-                ap, pap = _spmv(a, p, backend, dot=True)
-                ap = unwrap(ap)
-            else:
-                ap = unwrap(_spmv(a, p, backend))           # Ap = A @ p
+            ap, pap = spmv(p_in)                    # Ap = A @ p
         with jax.named_scope("cg.dot"):
-            if not fused_dot:
+            if pap is None:
                 pap = jnp.sum(p * ap)
-            alpha = r2 / pap
+            alpha = r2 / reduce(pap)
         with jax.named_scope("cg.update"):
             r_new = r - alpha * ap
         with jax.named_scope("cg.dot"):
-            r2_new = jnp.sum(r_new * r_new)
+            r2_new = reduce(jnp.sum(r_new * r_new))
         with jax.named_scope("cg.update"):
             beta = r2_new / r2
             x_new = x + alpha * p
             p_new = r_new + beta * p
         return (x_new, r_new, p_new, r2_new, k + 1)
 
-    init = (jnp.zeros_like(bv), bv, bv, jnp.sum(bv * bv), jnp.int32(0))
+    init = (jnp.zeros_like(bv), bv, bv, reduce(jnp.sum(bv * bv)),
+            jnp.int32(0))
     x, r, p, r2, k = arbb_while(cond, body, init)
     return x, r2, k
 
@@ -130,9 +128,16 @@ def _cg_jit_core(a: Matrix, bv, stop, max_iters: int, backend: Optional[str]):
     if spmv.scope == "mesh":
         from repro.distributed import numerics as dnum
         return dnum.cg_mesh(a, bv, stop=stop, max_iters=max_iters,
-                            variant=backend)
-    return _cg_loop(a, bv, stop, max_iters, backend,
-                    fused_dot=spmv.name == "dia")
+                            variant=backend, loop=_cg_loop)
+
+    def chip_spmv(p):                   # the selected variant, by name
+        if spmv.name != "dia":
+            return unwrap(registry.dispatch("solver_spmv", a, wrap(p),
+                                            variant=spmv.name)), None
+        ap, pap = registry.dispatch("solver_spmv", a, wrap(p),
+                                    variant="dia", dot=True)
+        return unwrap(ap), pap
+    return _cg_loop(bv, stop, max_iters, spmv=chip_spmv)
 
 
 cg_jit = call(_cg_jit_core, static_argnums=(3, 4))

@@ -517,6 +517,31 @@ class TestHaloExchange:
         assert "pad(" not in body
         assert gauge.value == 2 * 73 * 4
 
+    @pytest.mark.parametrize("level,mesh_name,variant", [
+        (ExecLevel.O2, None, "dia"), (ExecLevel.O3, "mesh4", "mesh_dia")],
+        ids=["chip", "mesh4"])
+    def test_both_scopes_run_the_solvers_loop(self, level, mesh_name,
+                                              variant, request, monkeypatch):
+        """One CG loop for one chip and the mesh: with the solver's
+        ``arbb_while`` made to return its initial state, ``cg_solve``
+        returns x = 0 after 0 iterations in either scope."""
+        dia, _ = _hpcg27(HPCG_GRID)
+        b = C.bind(np.ones(dia.shape[0], np.float32))
+        mesh = request.getfixturevalue(mesh_name) if mesh_name else None
+        jax.clear_caches()              # no loop traced before the patch
+        try:
+            with monkeypatch.context() as m, use_level(level, mesh):
+                m.setattr(solvers, "arbb_while",
+                          lambda cond, body, init: init)
+                assert registry.select("solver_spmv", dia,
+                                       b).name == variant
+                res = solvers.cg_solve(dia, b, stop=0.0, max_iters=5)
+                x, k = res.x.read(), int(res.iterations)
+        finally:
+            jax.clear_caches()          # none traced with it after
+        assert k == 0
+        np.testing.assert_array_equal(x, np.zeros(dia.shape[0]))
+
 
 def _called_from(hlo, name):
     """The text of HLO computation ``name`` and of every computation it
