@@ -2,15 +2,21 @@
 on the configuration's operator, from x0 = 0 to a tolerance on the
 recursive residual.
 
-Work of one solve, from shapes, per iteration:
+Work of one solve, from shapes: per iteration, the least HBM traffic of
+textbook CG on the chip (``bench.peaks`` says which vectors it holds):
 
-- one DIA SpMV, (ndiags + 2) * 4 B per row, with p.Ap taken as it streams;
-- the minimum vector passes of the textbook update, 9 * 4 B per row:
-  x += a p, r -= a Ap and r.r read x, p, r, Ap and write x, r (6);
-  p = r + b p reads r, p and writes p (3);
+- the stored diagonals, ndiags * 4 B per row, read once by the SpMV;
+- 9 accesses of 4 B per row to the n-vectors: x, p and Ap are each
+  written once and read once; r is written once and read twice, as its new
+  value gates b through r.r and then feeds p = r + b p.  The SpMV's p and
+  Ap are among them: x += a p with p = r + b p can ride in the next SpMV,
+  which streams p anyway;
+- less the accesses of the vectors the chip keeps in VMEM, most accessed
+  first: on a v5e, 3/4 of 128 MiB holds one 64 MiB vector of 256^3 rows,
+  so r's 3 accesses go;
 - 2 FLOPs per stored non-zero plus 10 per row (two dots, three axpys).
 
-So 72 B/row for the 7-point operator.
+So 64 B/row from HBM for the 7-point operator, 52 on a v5e.
 
 The right-hand sides are 1 + seeded N(0, 1), ``traffic["pool"]`` of them,
 cycled through by the window.  The constant's large smooth part fixes how
@@ -35,8 +41,8 @@ import numpy as np
 
 from bench.harness import ROOT, Work, load_module
 
-#: bytes per row of the minimum vector passes of one CG iteration
-VECTOR_BYTES_PER_ROW = 9 * 4
+#: accesses of each n-vector in one iteration of textbook CG, at the least
+VECTOR_ACCESSES = {"r": 3, "x": 2, "p": 2, "Ap": 2}
 #: FLOPs per row of the vector work of one CG iteration
 VECTOR_FLOPS_PER_ROW = 10
 
@@ -64,11 +70,21 @@ def level():
     return use_level(ExecLevel.O2)
 
 
+def iterations_work(rows: int, nnz: float, ndiags: int,
+                    iterations: float) -> Work:
+    """Work of ``iterations`` CG iterations over ``rows`` rows of a DIA
+    operator of ``ndiags`` diagonals holding ``nnz`` non-zeros."""
+    vector = 4 * rows
+    accesses = VECTOR_ACCESSES.values()
+    return Work(
+        flops=iterations * (2 * nnz + VECTOR_FLOPS_PER_ROW * rows),
+        hbm_bytes=iterations * (ndiags + sum(accesses)) * vector,
+        vectors=tuple((vector, iterations * k) for k in accesses))
+
+
 def work(op, iterations: float) -> Work:
     """Work of one solve of ``iterations``."""
-    return Work(
-        flops=iterations * (2 * op.nnz + VECTOR_FLOPS_PER_ROW * op.n),
-        hbm_bytes=iterations * (op.spmv_bytes + VECTOR_BYTES_PER_ROW * op.n))
+    return iterations_work(op.n, op.nnz, len(op.offsets), iterations)
 
 
 def x_err(x, x_ref):

@@ -6,10 +6,14 @@ runs the mesh CG (``solver_spmv`` -> ``mesh_dia``).  Each set runs exactly
 timed CG sets do.
 
 Work of one set, per chip (the peaks are one chip's), from shapes, per
-iteration: one DIA SpMV over the chip's rows, (ndiags + 2) * 4 B per row;
-the minimum vector passes, 9 * 4 B per row; 2 FLOPs per stored non-zero
-(the chip's share) plus 10 per row.  The halo's 2 * max|offset| rows are
-left out: under 0.6 % of x at the configuration's size.
+iteration: the CG cell's count (``entries/cg.py``) over the chip's rows,
+the least HBM traffic of textbook CG: the stored diagonals, ndiags * 4 B
+per row, and 9 vector accesses of 4 B per row (x, p, Ap 2 each, r 3),
+less those of the vectors the chip holds in VMEM; 2 FLOPs per stored
+non-zero (the chip's share) plus 10 per row.  At 360^3 rows a chip a
+vector is 186.6 MB, more than a v5e holds, so all 9 come from HBM: 144
+B/row.  The halo's 2 * max|offset| rows are left out: under 0.6 % of x at
+the configuration's size.
 
 The right-hand sides are b = A x*, ``traffic["pool"]`` of them, cycled
 through by the window.  The check compares each sampled set's x with the
@@ -55,11 +59,8 @@ def level(mesh):
 
 def work(op, iterations: float) -> Work:
     """Work of one set of ``iterations``, on one chip."""
-    return Work(
-        flops=iterations * (2 * op.nnz / op.shards
-                            + cg.VECTOR_FLOPS_PER_ROW * op.n_local),
-        hbm_bytes=iterations * (op.spmv_bytes
-                                + cg.VECTOR_BYTES_PER_ROW * op.n_local))
+    return cg.iterations_work(op.n_local, op.nnz / op.shards,
+                              len(op.offsets), iterations)
 
 
 class Cell(cg.Cell):

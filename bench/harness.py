@@ -44,9 +44,15 @@ class NoAccelerator(RuntimeError):
 
 @dataclasses.dataclass(frozen=True)
 class Work:
-    """Work of one call, counted from shapes."""
+    """Work of one call, counted from shapes.
+
+    ``hbm_bytes`` takes every access from HBM.  ``vectors`` lists the
+    n-vectors whose accesses a chip may keep on chip instead, each as
+    (bytes, accesses in the call); which of them fit is the chip's, and
+    ``bench.peaks.least_seconds`` takes their accesses off."""
     flops: float
     hbm_bytes: float
+    vectors: tuple[tuple[float, float], ...] = ()
 
 
 @dataclasses.dataclass
